@@ -1,6 +1,6 @@
-"""Kernel microbenchmarks: Pallas (interpret mode on this CPU container —
-timings are correctness-path numbers, not TPU perf) vs jnp references.
-On TPU the same pallas_call lowers to Mosaic with interpret=False."""
+"""Kernel microbenchmarks: Pallas vs jnp references. On the CPU the
+kernels run in the Pallas interpreter, so timings there are
+correctness-path numbers, not TPU speed; on a TPU they run compiled."""
 from __future__ import annotations
 
 import time

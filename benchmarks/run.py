@@ -20,6 +20,8 @@ def main() -> None:
     only = set(args.only.split(",")) if args.only else None
     rounds = 24 if args.full else 10
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (
         ablation,
         async_engine,

@@ -66,7 +66,6 @@ from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 import numpy as np
@@ -273,8 +272,8 @@ def sharded_uniform_twin(num_devices: int, cohort_size: int,
     n, u = num_devices, cohort_size
     blk = _check_mesh(n, u, mesh)
 
-    @partial(shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
-             check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
+             check_vma=False)
     def draw(key):
         gid = _block_gids(blk)
         noise = jax.random.uniform(
@@ -312,8 +311,8 @@ def sharded_channel_aware_twin(num_devices: int, cohort_size: int, ltfl,
         u, max(1, round(explore * u)))
     n_top = u - n_explore
 
-    @partial(shard_map, mesh=mesh, in_specs=(P("pop"), P()), out_specs=P(),
-             check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P("pop"), P()),
+             out_specs=P(), check_vma=False)
     def draw(ch, key):
         gid = _block_gids(blk)
         snr = jnp.float32(p_ref) * _mean_gain_dev(ch) / _noise_dev(w, ch)
@@ -360,8 +359,8 @@ def sharded_energy_aware_twin(ltfl, num_devices: int, cohort_size: int,
     w_cfg = ltfl.wireless
     e_max = float(ltfl.e_max)
 
-    @partial(shard_map, mesh=mesh, in_specs=(P("pop"), P()),
-             out_specs=(P(), P()), check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P("pop"), P()),
+             out_specs=(P(), P()), check_vma=False)
     def draw(ch, key):
         gid = _block_gids(blk)
         valid = gid < n

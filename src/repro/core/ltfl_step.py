@@ -28,8 +28,7 @@ one-compiled-call-per-round semantics.
 
 With ``use_kernels=True`` the 2-D-tileable leaves route through the Pallas
 kernels in repro.kernels.ops (block-prune norms/masking and the dynamic-
-bits stochastic quantizer) — interpret-mode on this CPU container,
-identical kernel bodies on real TPU.
+bits stochastic quantizer): compiled on a TPU, interpreted on the CPU.
 """
 from __future__ import annotations
 

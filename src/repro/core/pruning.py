@@ -31,25 +31,53 @@ def importance(w: jax.Array) -> jax.Array:
     return jnp.abs(w)
 
 
-def _rank_mask(a: jax.Array, rho: jax.Array) -> jax.Array:
-    """True for entries NOT among the floor(rho * n) smallest of |a|.
+def _bisect(pred, lo: int, hi: int) -> jax.Array:
+    """Smallest integer x in [lo, hi] at which the monotone predicate
+    ``pred`` (false ... false, true ... true) holds; hi if it never does
+    below hi."""
+    def step(_, bounds):
+        lo, hi = bounds
+        mid = lo + (hi - lo) // 2
+        ok = pred(mid)
+        return jnp.where(ok, lo, mid + 1), jnp.where(ok, mid, hi)
 
-    Rank-based (two argsorts) so ties are broken deterministically and
-    exactly floor(rho*n) entries prune — a quantile threshold with strict
-    comparison would zero *every* entry of a constant tensor.
+    iters = max(int(hi - lo).bit_length(), 1)
+    _, x = jax.lax.fori_loop(0, iters, step,
+                             (jnp.int32(lo), jnp.int32(hi)))
+    return x
+
+
+def rank_mask(a: jax.Array, rho: jax.Array) -> jax.Array:
+    """True for entries NOT among the floor(rho * n) smallest of ``a``
+    (a >= 0), ties broken by flat index: exactly ``ranks >= k`` with the
+    ranks of a stable argsort, so exactly floor(rho*n) entries prune — a
+    quantile threshold with strict comparison would zero *every* entry of
+    a constant tensor.
+
+    Found by bisection, not sorting: the k-th smallest value is searched
+    over the int32 bit patterns of the non-negative floats (ordered as the
+    values are; NaN sorts last), then the index cut among its ties. Each
+    step is one counting pass, so the program holds no sort: a sort of a
+    wide leaf costs the TPU compiler about half a minute per shape.
     """
-    flat = a.reshape(-1)
+    flat = a.reshape(-1).astype(jnp.float32)
     n = flat.size
     k = jnp.floor(jnp.clip(rho, 0.0, 1.0) * n).astype(jnp.int32)
-    ranks = jnp.argsort(jnp.argsort(flat))        # ascending rank of each entry
-    return (ranks >= k).reshape(a.shape)
+    bits = jax.lax.bitcast_convert_type(flat, jnp.int32)
+    # the k-th smallest value t (its bit pattern); t = 0 when k == 0
+    t = _bisect(lambda v: jnp.sum(bits <= v) >= k, 0, 0x7FFFFFFF)
+    ties = bits == t
+    cut = k - jnp.sum(bits < t)             # ties that prune, lowest first
+    idx = jnp.arange(n, dtype=jnp.int32)
+    m = _bisect(lambda i: jnp.sum(ties & (idx < i)) >= cut, 0, n)
+    return ((bits > t) | (ties & (idx >= m))).reshape(a.shape)
 
 
 def magnitude_prune(w: jax.Array, rho: jax.Array
                     ) -> Tuple[jax.Array, jax.Array]:
     """Zero the smallest-|w| rho-fraction of entries (Eq. 12-13).
     rho may be traced."""
-    mask = _rank_mask(jnp.abs(w.astype(jnp.float32)), rho)
+    mask = rank_mask(jnp.abs(w.astype(jnp.float32)), rho)
     return w * mask.astype(w.dtype), mask
 
 
@@ -102,7 +130,7 @@ def block_prune(w: jax.Array, rho: jax.Array, block: int = BLOCK
     tile_mask has shape (..., rows/block, cols/block).
     """
     imp = block_importance(w, block)
-    tile_mask = _rank_mask(imp, rho)                   # (..., tr, tc)
+    tile_mask = rank_mask(imp, rho)                   # (..., tr, tc)
     t = _tile_view(w, block)
     m = tile_mask[..., :, None, :, None].astype(w.dtype)
     pruned = (t * m).reshape(w.shape)
@@ -144,7 +172,7 @@ def prune_pytree(w: PyTree, rho: jax.Array, block: int = BLOCK,
             if use_kernels:
                 return kernel_block_leaf(x)
             imp = block_importance(x, block)
-            tile_mask = _rank_mask(imp, rho)
+            tile_mask = rank_mask(imp, rho)
             t = _tile_view(x, block)
             m = tile_mask[..., :, None, :, None]
             pruned = (t * m.astype(x.dtype)).reshape(x.shape)

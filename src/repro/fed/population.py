@@ -83,7 +83,6 @@ from typing import Any, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import LTFLConfig, WirelessConfig
@@ -230,9 +229,9 @@ def refresh_cohort_dev(cfg: WirelessConfig, mesh: Mesh,
     and the (N_pad,) leaves stay in place on their shards."""
     new_f, new_i = draw_fading_dev(cfg, key, cohort.shape[0])
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P("pop"), P("pop"), P("pop"), P(), P(), P(), P()),
-             out_specs=(P("pop"), P("pop"), P("pop")), check_rep=False)
+             out_specs=(P("pop"), P("pop"), P("pop")), check_vma=False)
     def scatter(fading, interference, fading_epoch, coh, f, i, epoch):
         blk = fading.shape[0]
         loc = coh - jax.lax.axis_index("pop").astype(jnp.int32) * blk
@@ -265,8 +264,8 @@ def gather_cohort_dev(mesh: Mesh, channel: ChannelArrays,
     plane then runs on the replicated view exactly as in the unsharded
     engine."""
 
-    @partial(shard_map, mesh=mesh, in_specs=(P(None, "pop"), P()),
-             out_specs=P(), check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(None, "pop"), P()),
+             out_specs=P(), check_vma=False)
     def gather(leaves, coh):
         blk = leaves.shape[-1]
         loc = coh - jax.lax.axis_index("pop").astype(jnp.int32) * blk
@@ -292,8 +291,9 @@ def gather_parts_dev(mesh: Mesh, table: jax.Array, sizes: jax.Array,
     residency stays at N_pad/S rows. Per-shard work is O(U * W);
     the (N_pad, W) table never materializes on one device."""
 
-    @partial(shard_map, mesh=mesh, in_specs=(P("pop", None), P("pop"), P()),
-             out_specs=(P(), P()), check_rep=False)
+    @partial(jax.shard_map, mesh=mesh,
+             in_specs=(P("pop", None), P("pop"), P()),
+             out_specs=(P(), P()), check_vma=False)
     def gather(tbl, sz, coh):
         blk = tbl.shape[0]
         loc = coh - jax.lax.axis_index("pop").astype(jnp.int32) * blk

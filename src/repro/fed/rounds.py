@@ -133,7 +133,7 @@ class FedRunner:
 
     ``eval_every`` evaluates test accuracy every k rounds (0 => never);
     ``use_kernels`` routes the 2-D quantization fast path through the
-    Pallas kernels (intended for real TPU; interpret mode on CPU);
+    Pallas kernels (compiled on a TPU, interpreted on the CPU);
     ``block_fading`` re-draws the per-device slow fading/interference
     state at the start of every round (lazily, for the scheduled cohort)
     — combined with ``LTFLScheme(recontrol_every=1)`` the controller

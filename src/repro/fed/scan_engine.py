@@ -447,10 +447,10 @@ class ScanRunner(FedRunner):
         self._n_pop_uploads = 0   # (N,)-state host->device upload events
         # one per (segment length, decide_first, single|sweep) trace
         self._n_traces = 0
-        self._seg_jit = jax.jit(self._segment, static_argnums=(3, 4))
+        self._seg_jit = jax.jit(self._segment, static_argnums=(4, 5))
         self._sweep_jit = jax.jit(
-            jax.vmap(self._segment, in_axes=(0, 0, 0, None, None)),
-            static_argnums=(3, 4))
+            jax.vmap(self._segment, in_axes=(0, 0, 0, None, None, None)),
+            static_argnums=(4, 5))
         # populated by run_sweep: bucket metadata of the last sweep
         # (signature, representative runner, lane indices) — the
         # compile-counter tests and benchmarks read trace counts off it
@@ -729,7 +729,7 @@ class ScanRunner(FedRunner):
     # ------------------------------------------------------------------ #
     # the compiled segment
     # ------------------------------------------------------------------ #
-    def _segment(self, carry, xs, consts, length: int,
+    def _segment(self, carry, xs, consts, data, length: int,
                  decide_first: bool = False):
         """One scanned segment. Traced once per distinct ``(length,
         decide_first)`` (and once more inside the run_sweep vmap);
@@ -746,7 +746,14 @@ class ScanRunner(FedRunner):
         ltfl = _laned_ltfl(self.ltfl, consts["cfg"])
         w = ltfl.wireless
         step_fn = self._step_fn
-        data = self._data_dev
+        if self._pop_mesh is not None:
+            # the round's tensor work is replicated over the 'pop' mesh:
+            # run it per device, since the SPMD partitioner refuses the
+            # Pallas (Mosaic) kernels it calls under use_kernels
+            step_fn = jax.shard_map(step_fn, mesh=self._pop_mesh,
+                                    in_specs=PartitionSpec(),
+                                    out_specs=PartitionSpec(),
+                                    check_vma=False)
         asy = self._async
         unbiased = self.participation == "unbiased"
         U, N, B = self.num_devices, self.population_size, self.batch_size
@@ -1206,11 +1213,11 @@ class ScanRunner(FedRunner):
         if self.rng == "host":
             xs, consts, ctl = self._prepare_host_segment(a, b)
             carry, log = self._seg_jit(self._host_carry(), xs, consts,
-                                       b - a, decide_first)
+                                       self._data_dev, b - a, decide_first)
         else:
             consts, ctl = self._prepare_device_segment(a, b)
             carry, log = self._seg_jit(self._device_carry(), None, consts,
-                                       b - a, decide_first)
+                                       self._data_dev, b - a, decide_first)
         self._absorb_segment(a, b, ctl, carry, log)
 
     def run(self, num_rounds: int, log_every: int = 0) -> List[RoundRecord]:
@@ -1240,6 +1247,19 @@ class ScanRunner(FedRunner):
         if self.rng == "device":
             self._sync_host_population()
         return self.history
+
+    def lower_segment(self, num_rounds: int) -> jax.stages.Lowered:
+        """AOT-lower the first segment that ``run(num_rounds)`` would
+        execute (device rng), for inspecting the program ``run`` compiles:
+        its HLO, memory analysis and compile time."""
+        if self.rng != "device":
+            raise ValueError("lower_segment needs rng='device'")
+        self._ensure_device_world()
+        a, b = self._segment_spans(0, num_rounds)[0]
+        consts, _ = self._prepare_device_segment(a, b)
+        return self._seg_jit.lower(self._device_carry(), None, consts,
+                                   self._data_dev, b - a,
+                                   self._decide_first(a))
 
     # ------------------------------------------------------------------ #
     # vmap over lanes (seeds, schemes, regimes, cohort grids)
@@ -1417,7 +1437,8 @@ class ScanRunner(FedRunner):
                     carries = stack([lane._host_carry()
                                      for lane in glanes])
                     carries, logs = rep._sweep_jit(
-                        carries, xss, constss, b - a, decide_first)
+                        carries, xss, constss, self._data_dev, b - a,
+                        decide_first)
                     ctls = [p[2] for p in preps]
                 else:
                     preps = [lane._prepare_device_segment(a, b)
@@ -1426,7 +1447,8 @@ class ScanRunner(FedRunner):
                     carries = stack([lane._device_carry()
                                      for lane in glanes])
                     carries, logs = rep._sweep_jit(
-                        carries, None, constss, b - a, decide_first)
+                        carries, None, constss, self._data_dev, b - a,
+                        decide_first)
                     ctls = [p[1] for p in preps]
                 for i, lane in enumerate(glanes):
                     lane._absorb_segment(a, b, ctls[i],

@@ -8,16 +8,21 @@ via @pl.when — the tile never reaches the MXU, so compute scales with
 
 Grid is (M/bm, N/bn, K/bk) with K innermost so each output tile is
 revisited across the contraction; a VMEM f32 scratch accumulates partial
-products and spills to the output dtype once, at the last K step.
+products and spills to the output dtype once, at the last K step. The
+tile mask sits in SMEM one output column of tiles at a time (transposed,
+as in ``block_prune``), so its footprint does not grow with the grid.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import interpret_mode
 
 DEFAULT_BLOCKS = (128, 128, 128)   # bm, bn, bk
 
@@ -29,7 +34,7 @@ def _bsmm_kernel(x_ref, w_ref, mask_ref, out_ref, acc_ref, *, n_k: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(mask_ref[0, 0] != 0)
+    @pl.when(mask_ref[0, k_idx] != 0)
     def _dot():
         acc_ref[...] += jnp.dot(
             x_ref[...].astype(jnp.float32),
@@ -44,7 +49,7 @@ def _bsmm_kernel(x_ref, w_ref, mask_ref, out_ref, acc_ref, *, n_k: int):
 @functools.partial(jax.jit, static_argnames=("blocks", "interpret"))
 def block_sparse_matmul(x: jax.Array, w: jax.Array, mask: jax.Array,
                         blocks=DEFAULT_BLOCKS,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: Optional[bool] = None) -> jax.Array:
     """x (M, K) @ w (K, N), skipping w tiles where mask (K/bk, N/bn) == 0."""
     m, k = x.shape
     k2, n = w.shape
@@ -61,10 +66,11 @@ def block_sparse_matmul(x: jax.Array, w: jax.Array, mask: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((1, 1), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((None, 1, n_k), lambda i, j, kk: (j, 0, 0),
+                         memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(x, w, mask.astype(jnp.int32))
+        interpret=interpret_mode(interpret),
+    )(x, w, mask.T.astype(jnp.int32)[:, None, :])

@@ -1,23 +1,21 @@
 """Jitted public wrappers around the Pallas kernels.
 
 These are the entry points the rest of the framework uses; each picks
-hardware-aligned block shapes, handles range/mask preparation, and (on
-this CPU container) runs the kernels in interpret mode. ``interpret`` flips
-to False on real TPU — the kernel bodies are identical.
+hardware-aligned block shapes and handles range/mask preparation. The
+kernels compile on a TPU and run in the Pallas interpreter on the CPU
+(``repro.kernels.backend``); any other backend is an error.
 """
 from __future__ import annotations
 
-import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.core.pruning import rank_mask
 from repro.kernels import block_prune as _bp
 from repro.kernels import block_sparse_matmul as _bsmm
 from repro.kernels import stochastic_quant as _sq
-
-INTERPRET = True  # CPU container: interpret mode. TPU deployments: False.
 
 
 def quantize_dequantize_2d(g: jax.Array, bits: int, key: jax.Array,
@@ -53,31 +51,27 @@ def quantize_dequantize_2d_dyn(g: jax.Array, bits: jax.Array, key: jax.Array,
     n_levels = jnp.maximum(
         jnp.round(2.0 ** jnp.asarray(bits, jnp.float32)) - 1.0, 1.0)
     rand = jax.random.uniform(key, g.shape, jnp.float32)
-    return _sq.stochastic_quant_dyn(g, rand, lo, hi, n_levels, block=block,
-                                    interpret=INTERPRET)
+    return _sq.stochastic_quant_dyn(g, rand, lo, hi, n_levels, block=block)
 
 
 def block_prune_2d(w: jax.Array, rho: float, block=(128, 128)
                    ) -> Tuple[jax.Array, jax.Array]:
     """Kernel-backed block pruning: returns (pruned_w, tile_mask).
 
-    Tile *ranking* happens on the tiny norms matrix (host-side math is
-    fine); the two bandwidth-heavy passes (norms, masking) are kernels.
+    Tiles are ranked on the small norms matrix by the jnp path's own rule
+    (``pruning.rank_mask``); the two bandwidth-heavy passes (norms,
+    masking) are kernels.
     """
-    norms = _bp.block_norms(w, block=block, interpret=INTERPRET)
-    flat = norms.reshape(-1)
-    k = jnp.floor(jnp.clip(rho, 0.0, 1.0) * flat.size).astype(jnp.int32)
-    ranks = jnp.argsort(jnp.argsort(flat))
-    mask = (ranks >= k).reshape(norms.shape)
-    pruned = _bp.apply_block_mask(w, mask, block=block, interpret=INTERPRET)
+    norms = _bp.block_norms(w, block=block)
+    mask = rank_mask(norms, rho)
+    pruned = _bp.apply_block_mask(w, mask, block=block)
     return pruned, mask
 
 
 def block_sparse_matmul(x: jax.Array, w: jax.Array, mask: jax.Array,
                         blocks=(128, 128, 128)) -> jax.Array:
     """x @ w skipping pruned w tiles (the rho compute saving on MXU)."""
-    return _bsmm.block_sparse_matmul(x, w, mask, blocks=blocks,
-                                     interpret=INTERPRET)
+    return _bsmm.block_sparse_matmul(x, w, mask, blocks=blocks)
 
 
 def pruned_matmul(x: jax.Array, w: jax.Array, rho: float,

@@ -1,8 +1,8 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
 Each kernel's test sweeps shapes/dtypes and asserts allclose against these
-references (kernels run in interpret mode on CPU; on TPU the same
-pallas_call lowers to Mosaic).
+references in the Pallas interpreter on the CPU; tests/test_tpu_compile.py
+compiles the same kernels for a TPU v5e.
 """
 from __future__ import annotations
 

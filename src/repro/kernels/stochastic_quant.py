@@ -3,18 +3,20 @@
 The gradient tensor streams HBM -> VMEM in (block_m, block_n) tiles; the
 kernel performs the |g| -> level -> stochastic-round -> dequant chain in
 registers, writing the quantized-value tensor back. The per-tensor range
-(lo, hi) rides along as a (1, 1) block in SMEM-like fashion. Randomness is
-supplied as a uniform tensor generated outside so interpret-mode (CPU)
-execution is bit-identical to the TPU lowering fed the same bits; on real
-TPU the wrapper can swap in pltpu PRNG without touching the kernel body.
+and level count ride along as one (1, 3) block. Randomness is supplied as a
+uniform tensor generated outside, so the kernel compiled on a TPU and the
+interpreter on the CPU consume the same bits as the jnp reference.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.backend import interpret_mode
 
 DEFAULT_BLOCK = (256, 256)
 
@@ -22,7 +24,8 @@ DEFAULT_BLOCK = (256, 256)
 @functools.partial(jax.jit, static_argnames=("bits", "block", "interpret"))
 def stochastic_quant(g: jax.Array, rand: jax.Array, lo: jax.Array,
                      hi: jax.Array, bits: int,
-                     block=DEFAULT_BLOCK, interpret: bool = True
+                     block=DEFAULT_BLOCK,
+                     interpret: Optional[bool] = None
                      ) -> jax.Array:
     """g, rand: (M, N); lo/hi: scalars. Returns Q(g) in g.dtype.
 
@@ -58,7 +61,8 @@ def _quant_kernel_dyn(g_ref, rand_ref, range_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def stochastic_quant_dyn(g: jax.Array, rand: jax.Array, lo: jax.Array,
                          hi: jax.Array, n_levels: jax.Array,
-                         block=DEFAULT_BLOCK, interpret: bool = True
+                         block=DEFAULT_BLOCK,
+                         interpret: Optional[bool] = None
                          ) -> jax.Array:
     """Traced-level-count variant: g, rand (M, N); lo/hi/n_levels scalars."""
     m, n = g.shape
@@ -77,5 +81,5 @@ def stochastic_quant_dyn(g: jax.Array, rand: jax.Array, lo: jax.Array,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), g.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(g, rand, rng)

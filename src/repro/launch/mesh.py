@@ -9,19 +9,27 @@ device state (the dry-run sets XLA_FLAGS before any jax import).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple, axes: tuple) -> jax.sharding.Mesh:
+    """Auto-axis mesh: GSPMD propagates shardings and ``shard_hint``'s
+    ``with_sharding_constraint`` applies (Explicit axes reject it)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """Tiny mesh for CI-style tests under --xla_force_host_platform_device_count=8."""
     shape = (2, 2, 2) if multi_pod else (2, 4)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def client_axes(multi_pod: bool, clients_on_pod_only: bool) -> tuple:

@@ -3,7 +3,9 @@
 Two modes:
   * ``--edge`` (paper-scale): the Section-6 CIFAR/ResNet federated run with
     the Algorithm-1 controller, channel simulation and delay/energy
-    accounting. Runs on this container's CPU.
+    accounting. ``--width 64`` (the default) is the paper's ResNet; runs on
+    a TPU, or on the CPU at a small ``--width`` with the kernels
+    interpreted.
   * datacenter (default): the LTFL federated step for an assigned
     architecture on an explicit device mesh — sized for real hardware; on
     CPU use --smoke to run a reduced config end-to-end.
@@ -17,32 +19,50 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import sys
 
 
-def run_edge(args) -> None:
+def edge_runner(*, scheme="ltfl", devices: int = 30,
+                train_samples: int = 18000, test_samples: int = 2000,
+                batch_size: int = 50, width: int = 64,
+                non_iid_alpha: float = 0.0, engine=None, **engine_kwargs):
+    """The Section-6 edge experiment, ready to ``run``: the pre-activation
+    ResNet with stem ``width`` and groups (w, 2w, 4w, 8w) — ``width=64``
+    is the paper's net — on seeded synthetic CIFAR-shaped data, with
+    ``devices`` clients under the Table 2 wireless parameters.
+    ``scheme`` is a name in ``ALL_SCHEMES`` or a scheme instance;
+    ``engine`` is the runner class (default ``FedRunner``);
+    ``engine_kwargs`` go to it. ``train_samples`` must cover the
+    clients' shard sizes (up to ``LTFLConfig.samples_max`` each)."""
     import jax
-    import numpy as np
     from repro.configs.base import LTFLConfig
     from repro.configs.ltfl_paper import ResNetConfig
     from repro.data import ArrayDataset, synthetic_cifar
     from repro.fed import ALL_SCHEMES, FedRunner
     from repro.models.resnet import ResNet
 
-    ltfl = LTFLConfig(num_devices=args.devices)
-    imgs, labels = synthetic_cifar(args.train_samples, seed=0)
-    timgs, tlabels = synthetic_cifar(args.test_samples, seed=1)
+    ltfl = LTFLConfig(num_devices=devices)
+    imgs, labels = synthetic_cifar(train_samples, seed=ltfl.seed)
+    timgs, tlabels = synthetic_cifar(test_samples, seed=ltfl.seed + 1)
     train = ArrayDataset({"images": imgs, "labels": labels})
     test = ArrayDataset({"images": timgs, "labels": tlabels})
-    model = ResNet(ResNetConfig(stem_channels=args.width,
-                                group_channels=(args.width, args.width * 2,
-                                                args.width * 4,
-                                                args.width * 4)))
+    model = ResNet(ResNetConfig(stem_channels=width,
+                                group_channels=(width, width * 2,
+                                                width * 4, width * 8)))
     params = model.init(jax.random.PRNGKey(ltfl.seed))
-    scheme = ALL_SCHEMES[args.scheme]()
-    runner = FedRunner(model, params, ltfl, train, test, scheme,
-                       batch_size=args.batch_size,
-                       non_iid_alpha=args.non_iid_alpha, seed=ltfl.seed)
+    if isinstance(scheme, str):
+        scheme = ALL_SCHEMES[scheme]()
+    engine = FedRunner if engine is None else engine
+    return engine(model, params, ltfl, train, test, scheme,
+                  batch_size=batch_size, non_iid_alpha=non_iid_alpha,
+                  seed=ltfl.seed, **engine_kwargs)
+
+
+def run_edge(args) -> None:
+    runner = edge_runner(scheme=args.scheme, devices=args.devices,
+                         train_samples=args.train_samples,
+                         test_samples=args.test_samples,
+                         batch_size=args.batch_size, width=args.width,
+                         non_iid_alpha=args.non_iid_alpha)
     runner.run(args.rounds, log_every=max(args.rounds // 20, 1))
     if args.out:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
@@ -99,10 +119,11 @@ def main() -> None:
     ap.add_argument("--scheme", default="ltfl")
     ap.add_argument("--rounds", type=int, default=60)
     ap.add_argument("--devices", type=int, default=30)
-    ap.add_argument("--train-samples", type=int, default=15000)
+    ap.add_argument("--train-samples", type=int, default=18000)
     ap.add_argument("--test-samples", type=int, default=2000)
-    ap.add_argument("--batch-size", type=int, default=64)
-    ap.add_argument("--width", type=int, default=32)
+    ap.add_argument("--batch-size", type=int, default=50)
+    ap.add_argument("--width", type=int, default=64,
+                    help="ResNet stem width; groups (w, 2w, 4w, 8w)")
     ap.add_argument("--non-iid-alpha", type=float, default=0.0)
     ap.add_argument("--out", default="")
     # datacenter mode
@@ -118,6 +139,8 @@ def main() -> None:
     ap.add_argument("--drop-prob", type=float, default=0.05)
     ap.add_argument("--prune-block", type=int, default=32)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.edge:
         run_edge(args)
     else:

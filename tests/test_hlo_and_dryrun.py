@@ -92,35 +92,35 @@ def test_collective_bytes_on_sharded_matmul():
     ("whisper-medium", "prefill_32k"),
     ("rwkv6-7b", "long_500k"),
 ])
-def test_dryrun_tiny_mesh(arch, shape):
+def test_dryrun_tiny_mesh(arch, shape, tmp_path):
     """Full-size configs lower + compile on the CI mesh (deliverable (e)
     machinery; the production 16x16 / 2x16x16 runs live in artifacts/)."""
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
-         "--shape", shape, "--test-mesh", "--out", "/tmp/dryrun_ci"],
+         "--shape", shape, "--test-mesh", "--out", str(tmp_path)],
         env=ENV, capture_output=True, text=True, timeout=420)
     assert out.returncode == 0, out.stderr[-3000:]
     assert "dry-run complete" in out.stdout
 
 
-def test_dryrun_scanned_train_variant():
+def test_dryrun_scanned_train_variant(tmp_path):
     """variant {"scan": R} AOT-lowers R federated rounds as one scanned
     segment (the scan engine's datacenter shape) and roughly R-scales the
     roofline FLOPs vs the single-round step."""
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch",
          "granite-8b", "--shape", "train_4k", "--test-mesh",
-         "--variant", '{"scan": 2}', "--out", "/tmp/dryrun_ci_scan"],
+         "--variant", '{"scan": 2}', "--out", str(tmp_path)],
         env=ENV, capture_output=True, text=True, timeout=420)
     assert out.returncode == 0, out.stderr[-3000:]
     assert "dry-run complete" in out.stdout
 
 
-def test_dryrun_skip_documented():
+def test_dryrun_skip_documented(tmp_path):
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch",
          "qwen1.5-32b", "--shape", "long_500k", "--test-mesh", "--out",
-         "/tmp/dryrun_ci"],
+         str(tmp_path)],
         env=ENV, capture_output=True, text=True, timeout=180)
     assert out.returncode == 0
     assert "SKIP" in out.stdout

@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import backend, ops, ref
 from repro.kernels.block_prune import apply_block_mask, block_norms
 from repro.kernels.block_sparse_matmul import block_sparse_matmul
 from repro.kernels.stochastic_quant import stochastic_quant
@@ -94,3 +94,18 @@ def test_fully_masked_matmul_is_zero():
     mask = jnp.zeros((2, 1), bool)
     y = block_sparse_matmul(x, w, mask)
     np.testing.assert_array_equal(np.asarray(y), 0.0)
+
+
+@pytest.mark.parametrize("platform,given,expected", [
+    ("cpu", None, True), ("tpu", None, False),
+    ("cpu", False, False), ("tpu", True, True), ("gpu", False, False)])
+def test_interpret_mode_follows_backend(monkeypatch, platform, given,
+                                        expected):
+    monkeypatch.setattr(backend.jax, "default_backend", lambda: platform)
+    assert backend.interpret_mode(given) is expected
+
+
+def test_interpret_mode_refuses_other_backends(monkeypatch):
+    monkeypatch.setattr(backend.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        backend.interpret_mode()

@@ -16,12 +16,49 @@ from repro.core.pruning import (
     magnitude_prune,
     magnitude_prune_pytree,
     prune_pytree,
+    rank_mask,
     tileable,
 )
 
 RHOS = (0.0, 0.07, 0.25, 0.5, 0.77, 0.9)
 SEEDS = (0, 17, 1234, 52341)
 RHO_SEED = list(itertools.product(RHOS, SEEDS))
+
+
+def _tie_heavy(kind, shape, rng):
+    """Non-negative float32 inputs with many multi-way ties."""
+    n = int(np.prod(shape))
+    if kind == "zeros":
+        a = np.zeros(n)
+    elif kind == "constant":
+        a = np.full(n, 0.75)
+    elif kind == "quantized":                    # 4 levels, as after Q(g)
+        a = rng.integers(0, 4, n) * 0.5
+    elif kind == "repeated_norms":               # few distinct tile norms
+        a = rng.choice(rng.uniform(0.0, 3.0, 3), n)
+    elif kind == "subnormal_zero_inf":
+        a = rng.choice(np.array([0.0, 1e-45, 1e-40, 2.0, np.inf]), n)
+    else:                                        # "distinct"
+        a = np.abs(rng.normal(size=n))
+    return a.astype(np.float32).reshape(shape)
+
+
+@pytest.mark.parametrize("rho", (0.0, 0.1, 1 / 3, 0.5, 0.9, 1.0))
+@pytest.mark.parametrize("kind", ("zeros", "constant", "quantized",
+                                  "repeated_norms", "subnormal_zero_inf",
+                                  "distinct"))
+def test_rank_mask_matches_stable_argsort(kind, rho):
+    """The bisection mask equals ``ranks >= floor(rho * n)`` with the ranks
+    of a stable argsort, ties broken by flat index."""
+    rng = np.random.default_rng(7)
+    for shape in ((1,), (7,), (64, 32), (3, 5, 8)):
+        a = _tie_heavy(kind, shape, rng)
+        flat = a.reshape(-1)
+        ranks = np.argsort(np.argsort(flat, kind="stable"), kind="stable")
+        k = int(np.floor(np.float32(rho) * np.float32(flat.size)))
+        expect = (ranks >= k).reshape(shape)
+        got = np.asarray(jax.jit(rank_mask)(jnp.asarray(a), jnp.float32(rho)))
+        np.testing.assert_array_equal(got, expect, err_msg=f"{shape}")
 
 
 @pytest.mark.parametrize("rho,seed", RHO_SEED)

@@ -236,6 +236,22 @@ def test_device_mode_runs_and_mixes_fading(world):
     assert scan.channel_epoch == 6
 
 
+def test_lower_segment_takes_the_data_pool_as_an_argument(world):
+    """``lower_segment`` is the program ``run`` compiles, and the training
+    pool is one of its arguments: a pool closed over as a constant would
+    be baked into the executable (hundreds of MB at paper scale)."""
+    model, params, train, test = world
+    scan = ScanRunner(model, params, LTFL, train, test, STCScheme(),
+                      batch_size=8, seed=0, eval_every=0, rng="device")
+    lowered = scan.lower_segment(3)
+    args, _ = lowered.args_info
+    assert args[3]["images"].shape == train.arrays["images"].shape
+    assert len(lowered.as_text()) < train.arrays["images"].nbytes
+    lowered.compile()
+    assert len(scan.run(3)) == 3
+    assert scan._n_traces == 1           # run reuses the lowered trace
+
+
 def test_device_mode_tolerates_zero_sample_device(world):
     """Regression for the padded-parts empty-shard crash: a registered
     zero-sample device used to IndexError the table build (`p[0]` on an

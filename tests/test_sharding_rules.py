@@ -5,9 +5,8 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.launch.sharding import base_rules, make_pspec
 
-# jax >= 0.4.36: AbstractMesh takes ((name, size), ...) pairs
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH3 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_divisible_dims_shard():
