@@ -37,6 +37,7 @@ from typing import Any, Callable, Dict, Tuple, Union
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.core.aggregation import aggregate
 from repro.core.compressors import (
     Compressor,
@@ -120,15 +121,19 @@ def make_fl_train_step(model, optimizer: Optimizer, n_clients: int,
 
     def client_grad(params, cbatch, rho):
         if prune:
-            pruned, masks = _prune(params, rho)
+            with jax.named_scope(spans.PRUNE):
+                pruned, masks = _prune(params, rho)
         else:
             pruned, masks = params, None
-        loss, g = jax.value_and_grad(model.loss)(pruned, cbatch)
-        if prune:
-            # pruned coordinates are neither trained nor uploaded (Eq. 32)
-            g = jax.tree_util.tree_map(
-                lambda gi, m: gi * m.astype(gi.dtype), g, masks)
-        rsq = range_sq_sum(g)
+        with jax.named_scope(spans.GRAD):
+            loss, g = jax.value_and_grad(model.loss)(pruned, cbatch)
+            if prune:
+                # pruned coordinates are neither trained nor uploaded
+                # (Eq. 32)
+                g = jax.tree_util.tree_map(
+                    lambda gi, m: gi * m.astype(gi.dtype), g, masks)
+        with jax.named_scope(spans.RANGE):
+            rsq = range_sq_sum(g)
         return g, loss, rsq
 
     def step(params: PyTree, opt_state: PyTree, comp_state: PyTree,
@@ -140,26 +145,29 @@ def make_fl_train_step(model, optimizer: Optimizer, n_clients: int,
             params, batch, controls["rho"])
         grads = constrain_stacked(grads)
         # int8_collective with an explicit compressor was rejected above
-        if quantize and int8_collective:
-            # beyond-paper wire format: move int8 levels across the client
-            # axis (all-gather of 1 byte/coord) instead of letting XLA
-            # all-reduce bf16 partial sums (2 bytes/coord x 2 passes);
-            # dequant + weighted mean happen after the gather, locally.
-            levels, scales = jax.vmap(quantize_int8_pytree)(
-                grads, keys[:n_clients])
-            if gather_shardings is not None:
-                levels = jax.tree_util.tree_map(
-                    jax.lax.with_sharding_constraint, levels,
-                    gather_shardings)
-            grads = jax.tree_util.tree_map(
-                lambda lv, sc: dequantize_int8(
-                    lv, sc.reshape((n_clients,) + (1,) * (lv.ndim - 1))),
-                levels, scales)
-        else:
-            grads, comp_state = jax.vmap(
-                comp.compress, in_axes=(0, 0, 0, 0))(
-                grads, controls["delta"], keys[:n_clients], comp_state)
-            grads = constrain_stacked(grads)
+        with jax.named_scope(spans.COMPRESS):
+            if quantize and int8_collective:
+                # beyond-paper wire format: move int8 levels across the
+                # client axis (all-gather of 1 byte/coord) instead of
+                # letting XLA all-reduce bf16 partial sums (2 bytes/coord
+                # x 2 passes); dequant + weighted mean happen after the
+                # gather, locally.
+                levels, scales = jax.vmap(quantize_int8_pytree)(
+                    grads, keys[:n_clients])
+                if gather_shardings is not None:
+                    levels = jax.tree_util.tree_map(
+                        jax.lax.with_sharding_constraint, levels,
+                        gather_shardings)
+                grads = jax.tree_util.tree_map(
+                    lambda lv, sc: dequantize_int8(
+                        lv, sc.reshape((n_clients,)
+                                       + (1,) * (lv.ndim - 1))),
+                    levels, scales)
+            else:
+                grads, comp_state = jax.vmap(
+                    comp.compress, in_axes=(0, 0, 0, 0))(
+                    grads, controls["delta"], keys[:n_clients], comp_state)
+                grads = constrain_stacked(grads)
 
         if "alpha" in controls:                    # host-sampled channel
             alpha = controls["alpha"].astype(jnp.float32)
@@ -172,20 +180,23 @@ def make_fl_train_step(model, optimizer: Optimizer, n_clients: int,
         # Eq. 19; "agg_denom" (population layer, unbiased partial
         # participation) fixes the normalizer at the population sample
         # total instead of renormalizing over the received cohort
-        g = aggregate(grads, controls["weights"], alpha,
-                      denom=controls.get("agg_denom"))
-        g = comp.server_transform(g)
+        with jax.named_scope(spans.AGGREGATE):
+            g = aggregate(grads, controls["weights"], alpha,
+                          denom=controls.get("agg_denom"))
+            g = comp.server_transform(g)
         lr = controls.get("lr")
-        if lr is None:
-            updates, opt_state = optimizer.update(g, opt_state, params)
-        elif optimizer.update_with_lr is None:
-            raise ValueError(
-                "controls['lr'] lanes the learning rate through the step, "
-                "but this optimizer does not provide update_with_lr")
-        else:
-            updates, opt_state = optimizer.update_with_lr(
-                g, opt_state, params, lr)
-        params = apply_updates(params, updates)                      # Eq. 20
+        with jax.named_scope(spans.UPDATE):
+            if lr is None:
+                updates, opt_state = optimizer.update(g, opt_state, params)
+            elif optimizer.update_with_lr is None:
+                raise ValueError(
+                    "controls['lr'] lanes the learning rate through the "
+                    "step, but this optimizer does not provide "
+                    "update_with_lr")
+            else:
+                updates, opt_state = optimizer.update_with_lr(
+                    g, opt_state, params, lr)
+            params = apply_updates(params, updates)                  # Eq. 20
         metrics = {
             "loss": jnp.mean(losses),
             "grad_norm": global_norm(g),

@@ -206,7 +206,7 @@ class AsyncRunner(ScanRunner):
         xs, consts, ctl0 = super()._prepare_host_segment(a, b)
         churn = self._async.churn
         if churn is not None:
-            cohorts = np.asarray(xs["cohort"])
+            cohorts = self._reads(xs["cohort"])
             alive_rows, drop_rows = [], []
             for i in range(b - a):
                 alive = self._alive_host
@@ -303,14 +303,20 @@ class AsyncRunner(ScanRunner):
         else:
             self._tau_dev = astate
         super()._absorb_segment(a, b, ctl, carry, log)
-        taus = np.asarray(log.tau, np.float64)
-        admitted = np.asarray(log.admitted, bool)
+
+    def _fetch_segment(self, carry, log):
+        host = super()._fetch_segment(carry, log)
+        host["admitted"] = self._reads(log.admitted, bool)
+        return host
+
+    def _record_rounds(self, a, b, ctl, host, gammas) -> None:
+        super()._record_rounds(a, b, ctl, host, gammas)
         for i, r in enumerate(range(a, b)):
             self.async_history.append({
                 "round": r,
-                "tau": taus[i],
-                "admitted": admitted[i],
-                "n_admitted": int(admitted[i].sum()),
+                "tau": host["tau"][i],
+                "admitted": host["admitted"][i],
+                "n_admitted": int(host["admitted"][i].sum()),
             })
 
     # host-visible staleness state (tests / serving) ------------------- #

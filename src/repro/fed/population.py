@@ -306,18 +306,20 @@ def gather_parts_dev(mesh: Mesh, table: jax.Array, sizes: jax.Array,
     return gather(table, sizes, cohort.astype(jnp.int32))
 
 
-def host_sync(population: Population, pop: PopulationArrays) -> None:
+def host_sync(population: Population, pop: PopulationArrays,
+              read=np.asarray) -> None:
     """Fold the device registry back into the host ``Population`` (one
     (N,) download — called once per ``run``, not per segment): realized
     fading/interference, per-device fading epochs and the population
     epoch. Post-run host inspection (views, host samplers, history
-    tooling) then sees exactly the state the scan left behind."""
+    tooling) then sees exactly the state the scan left behind. ``read``
+    makes each device-to-host read (the engine passes its counter)."""
     n = population.num_devices
     ch = population.channel
-    ch.fading_mean[:] = np.asarray(pop.channel.fading_mean)[:n]
-    ch.interference[:] = np.asarray(pop.channel.interference)[:n]
-    population.fading_epoch[:] = np.asarray(pop.fading_epoch)[:n]
-    population.epoch = int(pop.epoch)
+    ch.fading_mean[:] = read(pop.channel.fading_mean)[:n]
+    ch.interference[:] = read(pop.channel.interference)[:n]
+    population.fading_epoch[:] = read(pop.fading_epoch)[:n]
+    population.epoch = int(read(pop.epoch))
 
 
 @dataclass(frozen=True)

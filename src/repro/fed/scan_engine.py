@@ -116,6 +116,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
+from repro import spans
 from repro.core.channel import (
     ChannelArrays,
     draw_fading_dev,
@@ -447,6 +448,10 @@ class ScanRunner(FedRunner):
         self._n_pop_uploads = 0   # (N,)-state host->device upload events
         # one per (segment length, decide_first, single|sweep) trace
         self._n_traces = 0
+        # every device->host read of the engine goes through this counter;
+        # ``_seg`` counts absorbed segments (the ``seg`` of each span)
+        self._reads = spans.Reads()
+        self._seg = 0
         self._seg_jit = jax.jit(self._segment, static_argnums=(4, 5))
         self._sweep_jit = jax.jit(
             jax.vmap(self._segment, in_axes=(0, 0, 0, None, None, None)),
@@ -785,13 +790,15 @@ class ScanRunner(FedRunner):
                 controls["agg_denom"] = consts["agg_denom"]
             params, opt_state, comp_state, m = step_fn(
                 params, opt_state, comp_state, batch, controls, key)
-            range_sq = range_sq.at[cohort].set(m["range_sq"])
-            if accounting is None:
-                delay, energy = round_accounting_dev(
-                    ltfl, ch, payload, rho, power)
-            else:                        # async: buffered-round accounting
-                delay, energy = accounting
-            pers = packet_error_rate_dev(w, ch, power)
+            with jax.named_scope(spans.RANGE):
+                range_sq = range_sq.at[cohort].set(m["range_sq"])
+            with jax.named_scope(spans.CHANNEL):
+                if accounting is None:
+                    delay, energy = round_accounting_dev(
+                        ltfl, ch, payload, rho, power)
+                else:                    # async: buffered-round accounting
+                    delay, energy = accounting
+                pers = packet_error_rate_dev(w, ch, power)
             # gamma's inputs only — the Eq. 29 reduction happens on host
             # in f64 (_absorb_segment), NOT here: one numpy code path for
             # solo runs and every run_sweep lane keeps lane==solo gamma
@@ -803,8 +810,10 @@ class ScanRunner(FedRunner):
             gap_delta = jnp.where(delta > 0, delta, 32.0)
             denom = consts["agg_denom"] if unbiased else None
             if in_scan_eval:
-                acc = jax.lax.cond(r % eval_every == 0, eval_acc,
-                                   lambda p: jnp.float32(jnp.nan), params)
+                with jax.named_scope(spans.EVAL):
+                    acc = jax.lax.cond(r % eval_every == 0, eval_acc,
+                                       lambda p: jnp.float32(jnp.nan),
+                                       params)
             else:
                 acc = jnp.float32(jnp.nan)
             log = RoundLog(train_loss=m["loss"], delay=delay, energy=energy,
@@ -826,18 +835,21 @@ class ScanRunner(FedRunner):
                 params, opt_state, comp_state, range_sq = carry
                 ch = ChannelArrays(x["distance"], x["fading"],
                                    x["interference"], x["cpu"], x["ns"])
-                batch = {k: arr[x["batch_idx"]] for k, arr in data.items()}
+                with jax.named_scope(spans.SAMPLER):
+                    batch = {k: arr[x["batch_idx"]]
+                             for k, arr in data.items()}
                 weights, alpha, inclusion = (x["weights"], x["alpha"],
                                              x.get("inclusion"))
                 tau = admitted = accounting = None
                 if asy is not None:
                     masks = ((x["alive_c"], x["drop"])
                              if "alive_c" in x else None)
-                    (alpha, weights, inclusion, tau, admitted, accounting,
-                     astate) = self._admission(
-                        ltfl, ch, x["cohort"], alpha, weights, inclusion,
-                        consts["rho"], consts["power"], consts["payload"],
-                        astate, None, masks)
+                    with jax.named_scope(spans.CONTROL):
+                        (alpha, weights, inclusion, tau, admitted,
+                         accounting, astate) = self._admission(
+                            ltfl, ch, x["cohort"], alpha, weights,
+                            inclusion, consts["rho"], consts["power"],
+                            consts["payload"], astate, None, masks)
                 params, opt_state, comp_state, range_sq, log = finish(
                     params, opt_state, comp_state, range_sq, batch, ch,
                     x["cohort"], weights, alpha,
@@ -879,53 +891,60 @@ class ScanRunner(FedRunner):
                 # eager full-population redraw: O(N) vectorized on device
                 # (the host loop's LAZY per-cohort refresh is a host-side
                 # optimization; the realized distributions match)
-                fading, interference = draw_fading_dev(w, k_fade, N)
+                with jax.named_scope(spans.CHANNEL):
+                    fading, interference = draw_fading_dev(w, k_fade, N)
             ch_pop = ChannelArrays(
                 distance=consts["distance"], fading_mean=fading,
                 interference=interference, cpu_hz=consts["cpu"],
                 num_samples=consts["ns"])
-            # the sampler twin sees the round's CURRENT realization —
-            # in-scan scheduling tracks fading at per-round cadence
-            cohort, pi = twin.select(ch_pop, k_cohort)
-            ch = ch_pop.take(cohort)
-            sizes = jnp.take(consts["part_sizes"], cohort)
-            # maximum(sizes, 1): a zero-sample device's clamped draw reads
-            # its all-zero pad row — harmless, its aggregation weight
-            # (num_samples) is 0; sizes >= 1 draws are untouched
-            draws = jax.random.randint(k_batch, (U, B), 0,
-                                       jnp.maximum(sizes, 1)[:, None])
-            gidx = jnp.take_along_axis(
-                jnp.take(consts["parts_padded"], cohort, axis=0),
-                draws, axis=1)
-            batch = {k: arr[gidx] for k, arr in data.items()}
+            with jax.named_scope(spans.SAMPLER):
+                # the sampler twin sees the round's CURRENT realization —
+                # in-scan scheduling tracks fading at per-round cadence
+                cohort, pi = twin.select(ch_pop, k_cohort)
+                ch = ch_pop.take(cohort)
+                sizes = jnp.take(consts["part_sizes"], cohort)
+                # maximum(sizes, 1): a zero-sample device's clamped draw
+                # reads its all-zero pad row — harmless, its aggregation
+                # weight (num_samples) is 0; sizes >= 1 draws are untouched
+                draws = jax.random.randint(k_batch, (U, B), 0,
+                                           jnp.maximum(sizes, 1)[:, None])
+                gidx = jnp.take_along_axis(
+                    jnp.take(consts["parts_padded"], cohort, axis=0),
+                    draws, axis=1)
+                batch = {k: arr[gidx] for k, arr in data.items()}
             if program is not None:
-                dctl, ctl_state = program.controls(
-                    ctl_state, r, cohort, ch, jnp.take(range_sq, cohort),
-                    k_ctl, ltfl, decide=decide)
+                with jax.named_scope(spans.CONTROL):
+                    dctl, ctl_state = program.controls(
+                        ctl_state, r, cohort, ch,
+                        jnp.take(range_sq, cohort), k_ctl, ltfl,
+                        decide=decide)
                 rho, delta, power, payload = dctl
             else:
                 rho, delta, power, payload = (
                     consts["rho"], consts["delta"], consts["power"],
                     consts["payload"])
-            alpha = sample_transmissions_dev(w, ch, power, k_alpha)
+            with jax.named_scope(spans.CHANNEL):
+                alpha = sample_transmissions_dev(w, ch, power, k_alpha)
             if unbiased:
                 weights, inclusion = ch.num_samples / pi, pi
             else:
                 weights, inclusion = ch.num_samples, None
             tau = admitted = accounting = None
             if asy is not None:
-                (alpha, weights, inclusion, tau, admitted, accounting,
-                 astate) = self._admission(
-                    ltfl, ch, cohort, alpha, weights, inclusion,
-                    rho, power, payload, astate, k_churn, None)
+                with jax.named_scope(spans.CONTROL):
+                    (alpha, weights, inclusion, tau, admitted, accounting,
+                     astate) = self._admission(
+                        ltfl, ch, cohort, alpha, weights, inclusion,
+                        rho, power, payload, astate, k_churn, None)
             params, opt_state, comp_state, range_sq, log = finish(
                 params, opt_state, comp_state, range_sq, batch, ch,
                 cohort, weights, alpha, inclusion, k_step,
                 rho, delta, power, payload, r,
                 tau=tau, admitted=admitted, accounting=accounting)
             if program is not None and program.feedback is not None:
-                ctl_state = program.feedback(ctl_state, cohort,
-                                             log.train_loss, log.delay)
+                with jax.named_scope(spans.CONTROL):
+                    ctl_state = program.feedback(ctl_state, cohort,
+                                                 log.train_loss, log.delay)
             out = (params, opt_state, comp_state, range_sq,
                    fading, interference, key)
             if program is not None:
@@ -969,32 +988,40 @@ class ScanRunner(FedRunner):
             # schedule on LAST-KNOWN (possibly stale) CSI — the host
             # Population semantics — then lazily refresh the scheduled
             # devices' realizations for this epoch
-            cohort, pi = twin.select(pop.channel, k_cohort)
+            with jax.named_scope(spans.SAMPLER):
+                cohort, pi = twin.select(pop.channel, k_cohort)
             if block_fading:
-                pop = refresh_cohort_dev(w, mesh, pop, cohort, k_fade)
+                with jax.named_scope(spans.CHANNEL):
+                    pop = refresh_cohort_dev(w, mesh, pop, cohort, k_fade)
                 fading = pop.channel.fading_mean
                 interference = pop.channel.interference
                 fading_epoch = pop.fading_epoch
-            ch = gather_cohort_dev(mesh, pop.channel, cohort)
-            # the (N_pad, W) table stays sharded over 'pop'; only the
-            # cohort's (U, W) rows are assembled (psum-gather), exactly
-            # matching a replicated-table take — same draws, same indices
-            rows, sizes = gather_parts_dev(
-                mesh, consts["parts_padded"], consts["part_sizes"], cohort)
-            draws = jax.random.randint(k_batch, (U, B), 0,
-                                       jnp.maximum(sizes, 1)[:, None])
-            gidx = jnp.take_along_axis(rows, draws, axis=1)
-            batch = {k: arr[gidx] for k, arr in data.items()}
+            with jax.named_scope(spans.SAMPLER):
+                ch = gather_cohort_dev(mesh, pop.channel, cohort)
+                # the (N_pad, W) table stays sharded over 'pop'; only the
+                # cohort's (U, W) rows are assembled (psum-gather),
+                # exactly matching a replicated-table take — same draws,
+                # same indices
+                rows, sizes = gather_parts_dev(
+                    mesh, consts["parts_padded"], consts["part_sizes"],
+                    cohort)
+                draws = jax.random.randint(k_batch, (U, B), 0,
+                                           jnp.maximum(sizes, 1)[:, None])
+                gidx = jnp.take_along_axis(rows, draws, axis=1)
+                batch = {k: arr[gidx] for k, arr in data.items()}
             if program is not None:
-                dctl, ctl_state = program.controls(
-                    ctl_state, r, cohort, ch, jnp.take(range_sq, cohort),
-                    k_ctl, ltfl, decide=decide)
+                with jax.named_scope(spans.CONTROL):
+                    dctl, ctl_state = program.controls(
+                        ctl_state, r, cohort, ch,
+                        jnp.take(range_sq, cohort), k_ctl, ltfl,
+                        decide=decide)
                 rho, delta, power, payload = dctl
             else:
                 rho, delta, power, payload = (
                     consts["rho"], consts["delta"], consts["power"],
                     consts["payload"])
-            alpha = sample_transmissions_dev(w, ch, power, k_alpha)
+            with jax.named_scope(spans.CHANNEL):
+                alpha = sample_transmissions_dev(w, ch, power, k_alpha)
             if unbiased:
                 weights, inclusion = ch.num_samples / pi, pi
             else:
@@ -1003,18 +1030,20 @@ class ScanRunner(FedRunner):
             if asy is not None:
                 # async state stays REPLICATED (N,) — ordinary ops on the
                 # gathered (replicated) cohort view, outside shard_map
-                (alpha, weights, inclusion, tau, admitted, accounting,
-                 astate) = self._admission(
-                    ltfl, ch, cohort, alpha, weights, inclusion,
-                    rho, power, payload, astate, k_churn, None)
+                with jax.named_scope(spans.CONTROL):
+                    (alpha, weights, inclusion, tau, admitted, accounting,
+                     astate) = self._admission(
+                        ltfl, ch, cohort, alpha, weights, inclusion,
+                        rho, power, payload, astate, k_churn, None)
             params, opt_state, comp_state, range_sq, log = finish(
                 params, opt_state, comp_state, range_sq, batch, ch,
                 cohort, weights, alpha, inclusion, k_step,
                 rho, delta, power, payload, r,
                 tau=tau, admitted=admitted, accounting=accounting)
             if program is not None and program.feedback is not None:
-                ctl_state = program.feedback(ctl_state, cohort,
-                                             log.train_loss, log.delay)
+                with jax.named_scope(spans.CONTROL):
+                    ctl_state = program.feedback(ctl_state, cohort,
+                                                 log.train_loss, log.delay)
             out = (params, opt_state, comp_state, range_sq,
                    fading, interference, fading_epoch, epoch, key)
             if program is not None:
@@ -1051,90 +1080,111 @@ class ScanRunner(FedRunner):
         segmentation guarantees eval rounds are segment-final; under
         device control the in-scan eval head already measured it and the
         accuracy is read off the log."""
-        self.params, self.opt_state, self.comp_state = carry[:3]
-        cohorts = np.asarray(log.cohort, np.int64)
-
-        if self.rng != "device":
-            range_sq = np.asarray(carry[3], np.float64)
-            touched = np.unique(cohorts)
-            self._range_sq_pop[touched] = range_sq[touched]
-        else:
-            # keep the (N,)-state DEVICE-resident across segments (its
-            # leaves feed the next _device_carry directly); the host
-            # population syncs back lazily — once, at the end of run()
-            self._range_sq_dev = carry[3]
-            if self._pop_mesh is not None:
-                (fading, interference, fading_epoch, epoch,
-                 key) = carry[4:9]
-                self._pop_dev = PopulationArrays(
-                    channel=self._pop_dev.channel._replace(
-                        fading_mean=fading, interference=interference),
-                    fading_epoch=fading_epoch, epoch=epoch)
-                ctl_carry = carry[9] if self._ctl_program is not None \
-                    else None
+        seg = self._seg
+        with spans.span(spans.ABSORB, self._reads, seg=seg):
+            with spans.span(spans.FETCH, seg=seg):
+                host = self._fetch_segment(carry, log)
+            self.params, self.opt_state, self.comp_state = carry[:3]
+            cohorts = host["cohort"]
+            if self.rng != "device":
+                touched = np.unique(cohorts)
+                self._range_sq_pop[touched] = host["range_sq_pop"][touched]
             else:
-                fading, interference, key = carry[4], carry[5], carry[6]
-                self._fading_dev = fading
-                self._interference_dev = interference
-                ctl_carry = carry[7] if self._ctl_program is not None \
-                    else None
-            self._scan_key = key
-            self._host_pop_stale = True
-            if self._ctl_program is not None:
-                self._ctl_state = ctl_carry
-                if self._ctl_program.absorb is not None:
-                    self._ctl_program.absorb(
-                        self.scheme,
-                        jax.tree_util.tree_map(np.asarray, ctl_carry))
-            if self.block_fading:
-                # the scan advanced (b - a) fading epochs on device; keep
-                # the host epoch bookkeeping (PER caches, stale-decision
-                # checks) consistent
-                self._channel_epoch += b - a
-                self.population.epoch += b - a
-            self.cohort = cohorts[-1]
-            if self.control == "host" and \
-                    self.scheme.scan_recontrol_every(self):
-                # host recontrol reads the cohort channel view between
-                # segments — it must see the carried realization now,
-                # not at the end of run()
-                self._sync_host_population()
+                # keep the (N,)-state DEVICE-resident across segments (its
+                # leaves feed the next _device_carry directly); the host
+                # population syncs back lazily — once, at the end of run()
+                self._range_sq_dev = carry[3]
+                if self._pop_mesh is not None:
+                    (fading, interference, fading_epoch, epoch,
+                     key) = carry[4:9]
+                    self._pop_dev = PopulationArrays(
+                        channel=self._pop_dev.channel._replace(
+                            fading_mean=fading, interference=interference),
+                        fading_epoch=fading_epoch, epoch=epoch)
+                else:
+                    fading, interference, key = carry[4], carry[5], carry[6]
+                    self._fading_dev = fading
+                    self._interference_dev = interference
+                self._scan_key = key
+                self._host_pop_stale = True
+                if self._ctl_program is not None:
+                    self._ctl_state = self._ctl_carry(carry)
+                    if self._ctl_program.absorb is not None:
+                        with spans.span(spans.CTL_ABSORB, seg=seg):
+                            self._ctl_program.absorb(self.scheme,
+                                                     host["ctl"])
+                if self.block_fading:
+                    # the scan advanced (b - a) fading epochs on device;
+                    # keep the host epoch bookkeeping (PER caches,
+                    # stale-decision checks) consistent
+                    self._channel_epoch += b - a
+                    self.population.epoch += b - a
+                self.cohort = cohorts[-1]
+                if self.control == "host" and \
+                        self.scheme.scan_recontrol_every(self):
+                    # host recontrol reads the cohort channel view between
+                    # segments — it must see the carried realization now,
+                    # not at the end of run()
+                    self._sync_host_population()
+            with spans.span(spans.GAMMA, seg=seg):
+                # Eq. 29 from the logged per-round input vectors, reduced
+                # HERE in float64: solo runs and run_sweep lanes share this
+                # exact numpy path, so lane==solo gamma is bitwise by
+                # construction (in-jit reductions drift a ulp between the
+                # solo and sweep-vmapped traces — see the module
+                # docstring). Async: per-device staleness rides the log
+                # and enters the same reduction (the staleness-HT
+                # convention — repro.core.convergence module docstring);
+                # tau = 0 adds exactly +0.0, so the sync-degenerate gammas
+                # stay bitwise.
+                incl, denoms, taus = (host["inclusion"], host["agg_denom"],
+                                      host["tau"])
+                gammas = np.asarray([
+                    gamma(self.ltfl, host["range_sq"][i],
+                          host["gap_delta"][i], host["rho_u"][i],
+                          host["pers"][i], host["ns_u"][i],
+                          **({"inclusion": incl[i],
+                              "population_samples": float(denoms[i])}
+                             if incl is not None else {}),
+                          **({"staleness": taus[i]}
+                             if taus is not None else {}))
+                    for i in range(b - a)], np.float64)
+            with spans.span(spans.RECORDS, seg=seg):
+                self._record_rounds(a, b, ctl, host, gammas)
+        self._seg += 1
 
-        losses = np.asarray(log.train_loss, np.float64)
-        delays = np.asarray(log.delay, np.float64)
-        energies = np.asarray(log.energy, np.float64)
-        received = np.asarray(log.received, np.float64)
-        # Eq. 29 from the logged per-round input vectors, reduced HERE in
-        # float64: solo runs and run_sweep lanes share this exact numpy
-        # path, so lane==solo gamma is bitwise by construction (in-jit
-        # reductions drift a ulp between the solo and sweep-vmapped
-        # traces — see the module docstring)
-        rsqs = np.asarray(log.range_sq, np.float64)
-        gds = np.asarray(log.gap_delta, np.float64)
-        rhos_u = np.asarray(log.rho_u, np.float64)
-        perss = np.asarray(log.pers, np.float64)
-        nss = np.asarray(log.ns_u, np.float64)
-        incl = (np.asarray(log.inclusion, np.float64)
-                if log.inclusion is not None else None)
-        denoms = (np.asarray(log.agg_denom, np.float64)
-                  if log.agg_denom is not None else None)
-        # async: per-device staleness rides the log and enters the same
-        # host float64 Eq. 29 reduction (the staleness-HT convention —
-        # repro.core.convergence module docstring). tau = 0 adds exactly
-        # +0.0, so the sync-degenerate gammas stay bitwise.
-        taus = (np.asarray(log.tau, np.float64)
-                if log.tau is not None else None)
-        gammas = np.asarray([
-            gamma(self.ltfl, rsqs[i], gds[i], rhos_u[i], perss[i], nss[i],
-                  **({"inclusion": incl[i],
-                      "population_samples": float(denoms[i])}
-                     if incl is not None else {}),
-                  **({"staleness": taus[i]} if taus is not None else {}))
-            for i in range(b - a)], np.float64)
-        accs = np.asarray(log.test_acc, np.float64)
-        rho_means = np.asarray(log.rho_mean, np.float64)
-        delta_means = np.asarray(log.delta_mean, np.float64)
-        power_means = np.asarray(log.power_mean, np.float64)
+    def _ctl_carry(self, carry):
+        """The control program's state among a device-rng carry's leaves."""
+        return carry[9] if self._pop_mesh is not None else carry[7]
+
+    def _fetch_segment(self, carry, log) -> Dict[str, Any]:
+        """Every device-to-host read of a segment's results, through the
+        counting reader: the log's cohorts, the host-rng range statistic
+        or the control program's carry (when its ``absorb`` reads it),
+        then the log's fields (None stays None)."""
+        read = self._reads
+        host: Dict[str, Any] = {"cohort": read(log.cohort, np.int64)}
+        if self.rng != "device":
+            host["range_sq_pop"] = read(carry[3], np.float64)
+        elif self._ctl_program is not None and \
+                self._ctl_program.absorb is not None:
+            host["ctl"] = jax.tree_util.tree_map(read,
+                                                 self._ctl_carry(carry))
+        for field in ("train_loss", "delay", "energy", "received",
+                      "range_sq", "gap_delta", "rho_u", "pers", "ns_u",
+                      "inclusion", "agg_denom", "tau", "test_acc",
+                      "rho_mean", "delta_mean", "power_mean"):
+            value = getattr(log, field)
+            host[field] = None if value is None else read(value, np.float64)
+        return host
+
+    def _record_rounds(self, a: int, b: int, ctl, host: Dict[str, Any],
+                       gammas: np.ndarray) -> None:
+        """Append rounds [a, b)'s ``RoundRecord``s and feed the scheme's
+        ``post_round``."""
+        losses, delays, energies = (host["train_loss"], host["delay"],
+                                    host["energy"])
+        taus = host["tau"]
         device_ctl = self.control == "device"
         # a control program's feedback IS the scheme's post_round, traced
         # — calling both would double-apply it
@@ -1146,7 +1196,7 @@ class ScanRunner(FedRunner):
             self._cum_energy += float(energies[i])
             eval_due = bool(self.eval_every and r % self.eval_every == 0)
             if device_ctl:
-                test_acc = float(accs[i])
+                test_acc = float(host["test_acc"][i])
             else:
                 assert not eval_due or i == (b - a - 1), \
                     "segmentation must end segments at eval rounds"
@@ -1159,15 +1209,15 @@ class ScanRunner(FedRunner):
                 energy=float(energies[i]),
                 cum_delay=self._cum_delay,
                 cum_energy=self._cum_energy,
-                received=int(received[i]),
+                received=int(host["received"][i]),
                 gamma=float(gammas[i]),
-                rho_mean=(float(rho_means[i]) if ctl is None
+                rho_mean=(float(host["rho_mean"][i]) if ctl is None
                           else float(np.mean(ctl.rho))),
-                delta_mean=(float(delta_means[i]) if ctl is None
+                delta_mean=(float(host["delta_mean"][i]) if ctl is None
                             else float(np.mean(ctl.delta))),
-                power_mean=(float(power_means[i]) if ctl is None
+                power_mean=(float(host["power_mean"][i]) if ctl is None
                             else float(np.mean(ctl.power))),
-                cohort=cohorts[i].tolist() if partial else [],
+                cohort=host["cohort"][i].tolist() if partial else [],
                 participation=self.cohort_size / self.population_size,
                 staleness=(float(np.mean(taus[i]))
                            if taus is not None else 0.0),
@@ -1189,35 +1239,42 @@ class ScanRunner(FedRunner):
         the old per-segment (N,) download/upload round trip."""
         if not self._host_pop_stale:
             return
-        if self._pop_mesh is not None:
-            host_sync(self.population, self._pop_dev)
-        else:
-            ch = self.population.channel
-            ch.fading_mean[:] = np.asarray(self._fading_dev)
-            ch.interference[:] = np.asarray(self._interference_dev)
-            if self.block_fading:
-                # the unsharded device body redraws the FULL population
-                # each epoch (eager), so every realization is current
-                self.population.fading_epoch[:] = self.population.epoch
-        n = self.population_size
-        self._range_sq_pop[:] = np.asarray(self._range_sq_dev,
-                                           np.float64)[:n]
-        self.channel = self.population.view(self.cohort)
-        self._host_pop_stale = False
+        read = self._reads
+        with spans.span(spans.SYNC, read, seg=self._seg):
+            if self._pop_mesh is not None:
+                host_sync(self.population, self._pop_dev, read=read)
+            else:
+                ch = self.population.channel
+                ch.fading_mean[:] = read(self._fading_dev)
+                ch.interference[:] = read(self._interference_dev)
+                if self.block_fading:
+                    # the unsharded device body redraws the FULL
+                    # population each epoch (eager), so every realization
+                    # is current
+                    self.population.fading_epoch[:] = self.population.epoch
+            n = self.population_size
+            self._range_sq_pop[:] = read(self._range_sq_dev, np.float64)[:n]
+            self.channel = self.population.view(self.cohort)
+            self._host_pop_stale = False
 
     # ------------------------------------------------------------------ #
     # the public loop
     # ------------------------------------------------------------------ #
     def _run_segment(self, a: int, b: int) -> None:
-        decide_first = self._decide_first(a)
-        if self.rng == "host":
-            xs, consts, ctl = self._prepare_host_segment(a, b)
-            carry, log = self._seg_jit(self._host_carry(), xs, consts,
-                                       self._data_dev, b - a, decide_first)
-        else:
-            consts, ctl = self._prepare_device_segment(a, b)
-            carry, log = self._seg_jit(self._device_carry(), None, consts,
-                                       self._data_dev, b - a, decide_first)
+        with spans.span(spans.PREPARE, seg=self._seg) as t:
+            decide_first = self._decide_first(a)
+            if self.rng == "host":
+                xs, consts, ctl = self._prepare_host_segment(a, b)
+                carry = self._host_carry()
+            else:
+                xs = None
+                consts, ctl = self._prepare_device_segment(a, b)
+                carry = self._device_carry()
+            t.set_metadata(uploads=self._n_pop_uploads)
+        with spans.span(spans.DISPATCH, seg=self._seg, rounds=b - a) as t:
+            carry, log = self._seg_jit(carry, xs, consts, self._data_dev,
+                                       b - a, decide_first)
+            t.set_metadata(traces=self._n_traces)
         self._absorb_segment(a, b, ctl, carry, log)
 
     def run(self, num_rounds: int, log_every: int = 0) -> List[RoundRecord]:
@@ -1229,23 +1286,25 @@ class ScanRunner(FedRunner):
                 "has length 1 and nothing is amortized; pass eval_every=0, "
                 "an eval cadence of k rounds, or control='device' (the "
                 "in-scan eval head)", stacklevel=2)
-        self._ensure_device_world()
-        # round numbering restarts at 0 on every run() call, exactly like
-        # FedRunner.run (history keeps appending; eval cadence and LTFL's
-        # recontrol_every schedule restart with the numbering)
-        for a, b in self._segment_spans(0, num_rounds):
-            self._run_segment(a, b)
-            if log_every:
-                for rec in self.history[-(b - a):]:
-                    if rec.round % log_every == 0:
-                        print(f"[{self.scheme.name}] round={rec.round:4d} "
-                              f"loss={rec.train_loss:.4f} "
-                              f"acc={rec.test_acc:.3f} "
-                              f"delay={rec.delay:9.1f}s "
-                              f"energy={rec.energy:8.2f}J "
-                              f"recv={rec.received}/{self.num_devices}")
-        if self.rng == "device":
-            self._sync_host_population()
+        with spans.span(spans.RUN, seg=self._seg):
+            self._ensure_device_world()
+            # round numbering restarts at 0 on every run() call, exactly
+            # like FedRunner.run (history keeps appending; eval cadence and
+            # LTFL's recontrol_every schedule restart with the numbering)
+            for a, b in self._segment_spans(0, num_rounds):
+                self._run_segment(a, b)
+                if log_every:
+                    for rec in self.history[-(b - a):]:
+                        if rec.round % log_every == 0:
+                            print(f"[{self.scheme.name}] "
+                                  f"round={rec.round:4d} "
+                                  f"loss={rec.train_loss:.4f} "
+                                  f"acc={rec.test_acc:.3f} "
+                                  f"delay={rec.delay:9.1f}s "
+                                  f"energy={rec.energy:8.2f}J "
+                                  f"recv={rec.received}/{self.num_devices}")
+            if self.rng == "device":
+                self._sync_host_population()
         return self.history
 
     def lower_segment(self, num_rounds: int) -> jax.stages.Lowered:

@@ -59,6 +59,7 @@ def block_norms(w: jax.Array, block=DEFAULT_BLOCK,
         out_specs=_row_of_tiles(grid),
         out_shape=jax.ShapeDtypeStruct((grid[0], 1, grid[1]), jnp.float32),
         interpret=interpret_mode(interpret),
+        name="block_norms",
     )(w).reshape(grid)
 
 
@@ -86,4 +87,5 @@ def apply_block_mask(w: jax.Array, mask: jax.Array, block=DEFAULT_BLOCK,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), w.dtype),
         interpret=interpret_mode(interpret),
+        name="apply_block_mask",
     )(w, mask.astype(jnp.float32).reshape(grid[0], 1, grid[1]))

@@ -73,4 +73,5 @@ def block_sparse_matmul(x: jax.Array, w: jax.Array, mask: jax.Array,
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret_mode(interpret),
+        name="block_sparse_matmul",
     )(x, w, mask.T.astype(jnp.int32)[:, None, :])

@@ -82,4 +82,5 @@ def stochastic_quant_dyn(g: jax.Array, rand: jax.Array, lo: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), g.dtype),
         interpret=interpret_mode(interpret),
+        name="stochastic_quant_dyn",
     )(g, rand, rng)
