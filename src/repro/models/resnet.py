@@ -39,13 +39,28 @@ def _gn_spec(c):
 
 
 def group_norm(x: jax.Array, gamma, beta, groups=GN_GROUPS, eps=1e-5):
+    """GroupNorm of (B, H, W, C) over ``min(groups, C)`` channel groups,
+    with the two-pass variance of ``jnp.var``.
+
+    Channels stay the minor dimension from input to output: sums are taken
+    per channel over H and W, and only those (B, C) sums are grouped. A
+    reshape of the activation to (..., g, C // g) would split the TPU's
+    128-wide lane dimension whenever C // g < 128, and cost a relayout of
+    the whole tensor each way, in the forward pass and in its gradient.
+    """
     B, H, W, C = x.shape
     g = min(groups, C)
-    xg = x.reshape(B, H, W, g, C // g)
-    mu = jnp.mean(xg, axis=(1, 2, 4), keepdims=True)
-    var = jnp.var(xg, axis=(1, 2, 4), keepdims=True)
-    xg = (xg - mu) * jax.lax.rsqrt(var + eps)
-    return xg.reshape(B, H, W, C) * gamma + beta
+    n = H * W * (C // g)
+
+    def group_total(s):                      # (B, C) -> (B, C)
+        t = s.reshape(B, g, C // g).sum(-1)
+        return jnp.repeat(t, C // g, axis=-1)
+
+    mu = group_total(jnp.sum(x, axis=(1, 2))) / n
+    d = x - mu[:, None, None, :]
+    var = group_total(jnp.sum(d * d, axis=(1, 2))) / n
+    scale = jax.lax.rsqrt(var + eps) * gamma
+    return d * scale[:, None, None, :] + beta
 
 
 def conv2d(x, w, stride=1):
