@@ -30,11 +30,13 @@ def build(cell, seed: int, params, train, test):
                       **cfg["ltfl"])
     scheme = module("schemes", t["scheme"]).program(t)
     sampler = module("samplers", dep["sampler"]).program()
-    model = module("families", cfg["family"]).program_model(cfg["model"])
+    fam = module("families", cfg["family"])
+    model = fam.program_model(cfg["model"])
     partial = n != u
     runner = ScanRunner(
         model, params, ltfl, ArrayDataset(train), ArrayDataset(test), scheme,
         batch_size=dep["batch_size"], non_iid_alpha=dep["non_iid_alpha"],
+        label_key=fam.PARTITION_KEY or "labels",
         seed=int(seed), eval_every=t["eval_every"],
         use_kernels=t["use_kernels"], block_fading=t["block_fading"],
         population_size=n if partial else None,
@@ -93,11 +95,25 @@ def round_keys(key: jax.Array):
                  "alpha": k_alpha, "step": k_step}
 
 
-def aggregate(grads, samples: jax.Array, alpha: jax.Array):
-    """Eq. 19: the received devices' gradients averaged with weights
-    N_u; nothing received averages to zero."""
-    wts = samples.astype(jnp.float32) * alpha
+def weights(samples: jax.Array, alpha: jax.Array) -> jax.Array:
+    """Eq. 19's weight of each device: N_u where its upload was
+    received, else 0."""
+    return samples.astype(jnp.float32) * alpha
+
+
+def normalize(summed, wts: jax.Array, term=lambda s: s):
+    """Eq. 19's weighted sum of the gradients, ``term`` of each leaf of
+    ``summed``, over the total weight; nothing received averages to
+    zero."""
     total = jnp.sum(wts)
     return jax.tree_util.tree_map(
-        lambda g: jnp.where(total > 0, jnp.tensordot(wts, g, axes=1)
-                            / jnp.maximum(total, 1e-12), 0.0), grads)
+        lambda s: jnp.where(total > 0, term(s) / jnp.maximum(total, 1e-12),
+                            0.0), summed)
+
+
+def aggregate(grads, samples: jax.Array, alpha: jax.Array):
+    """Eq. 19: the received devices' gradients averaged with weights
+    N_u (a cohort streamed in chunks adds up the weighted gradients
+    itself and ends with ``normalize``)."""
+    wts = weights(samples, alpha)
+    return normalize(grads, wts, lambda g: jnp.tensordot(wts, g, axes=1))

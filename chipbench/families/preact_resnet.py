@@ -17,6 +17,15 @@ import jax.numpy as jnp
 
 PyTree = Any
 
+# the batch key the reference hands to ``loss`` beside "labels", and the
+# key of one class label per sample that the partition reads
+INPUT_KEY = "images"
+PARTITION_KEY = "labels"
+# ``flops_per_sample`` over XLA's CPU count of one sample's loss and
+# gradient: the dense count against XLA's, which leaves out the zero
+# border of a "SAME" convolution (see ``flops_per_sample``)
+XLA_FLOPS_RATIO = (1.08, 1.16)
+
 
 def _conv(k: int, cin: int, cout: int) -> Tuple[int, ...]:
     return (k, k, cin, cout)
@@ -167,7 +176,7 @@ def conv_layers(m: Dict) -> List[Tuple[int, int, int, int]]:
     return out
 
 
-def flops_per_image(m: Dict) -> int:
+def flops_per_sample(m: Dict) -> int:
     """Model FLOPs of one image's forward and backward pass: 2 per
     multiply-add of every convolution and of the head, forward once and
     backward twice (the gradients of the input and of the weights), less

@@ -96,7 +96,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
 
     # ---- set-up ---------------------------------------------------------- #
     params0 = fam.init_params(cfg["model"], traffic.weights_key(seed))
-    train, test = traffic.dataset(seed, cfg)
+    train, test = traffic.samples(seed, cfg)
     runner = engine.build(cell, seed, params0, train, test)
     first = engine.first_call(runner, rounds)
     params_after = runner.params

@@ -1,5 +1,5 @@
 """The clients' forward and backward model FLOPs per round (cohort x
-batch x the family's FLOPs per image; no pruning, quantizer, control or
+batch x the family's FLOPs per sample; no pruning, quantizer, control or
 eval work), times the rounds completed in the traced window, over the
 window, the chips and the chip's bf16 peak, in %."""
 
@@ -9,6 +9,6 @@ def read(ctx):
         return None
     dep = ctx.cell.config["deployment"]
     per_round = (dep["cohort"] * dep["batch_size"]
-                 * ctx.family.flops_per_image(ctx.cell.config["model"]))
+                 * ctx.family.flops_per_sample(ctx.cell.config["model"]))
     return 100.0 * per_round * ctx.rounds / (
         ctx.trace.window_s * ctx.chips * ctx.peaks["bf16_flops"])

@@ -20,6 +20,24 @@ new entries and no edit:
 * a per-layer metric: ``metrics/<metric>.py``, whose ``read(ctx)``
   returns the value, or None where the run has nothing to read;
 * the chip's peaks: ``peaks.json``, keyed by ``device_kind``.
+
+A family defines:
+
+* ``init_params(m, key)``: the weights of the model sizes ``m``, as the
+  program reads them, in one jitted call; ``num_params(m)``;
+* ``INPUT_KEY``: the key of the batch whose rows the reference hands to
+  ``loss`` beside those of ``"labels"``; ``PARTITION_KEY``: the key of
+  one class label per sample that the partition reads, or None to
+  partition by the number of samples (``non_iid_alpha`` 0);
+* ``loss(params, inputs, labels, m, precision)``: the plain reference;
+* ``flops_per_sample(m)``: model FLOPs of one sample's forward and
+  backward pass; ``XLA_FLOPS_RATIO``: the range that count keeps to
+  against XLA's CPU count of the same loss and gradient;
+* ``program_model(m)``: the system under test;
+* optionally ``dataset(seed, cfg) -> (train, test)``, dicts of arrays
+  keyed as the program model's batch; without it the samples are
+  ``traffic.dataset``'s images and class labels;
+* what a metric of its cells reads, such as ``quant_entries(m)``.
 """
 from __future__ import annotations
 

@@ -1,9 +1,11 @@
-"""Inputs made from the seed: the weights' key, the images and labels, and
-the program's seeded draws of its devices and their data shards.
+"""Inputs made from the seed: the weights' key, the samples, and the
+program's seeded draws of its devices and their data shards.
 
 Every input of a run comes from ``--seed`` through here. The benchmark
 makes the weights and the data and hands them to the program; the
-reference makes them again from the same seed. ``devices_and_shards``
+reference makes them again from the same seed. A family that defines
+``dataset(seed, cfg)`` makes its own samples (``samples``); the others
+get the images and labels of ``dataset`` below. ``devices_and_shards``
 repeats the program's own seeded draws of the device registry and the
 shards (the order of ``FedRunner.__init__``: Table 2 device draws, then
 the partition), so the reference reads the same rows the program's
@@ -16,6 +18,8 @@ from typing import Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from chipbench.spec import family
 
 # stream tags under one seed
 _WEIGHTS, _IMAGES, _LABELS = 1, 2, 3
@@ -75,6 +79,24 @@ def dataset(seed: int, cfg: Dict):
             {"images": np.asarray(x_test), "labels": y_test})
 
 
+def samples(seed: int, cfg: Dict):
+    """(train, test) as dicts of arrays keyed as the program model's batch:
+    the family's own ``dataset`` where it defines one, else the images
+    and labels of ``dataset`` here."""
+    make = getattr(family(cfg["family"]), "dataset", dataset)
+    return make(seed, cfg)
+
+
+def shard_pool(cfg: Dict, train: Dict):
+    """What the partition reads: the family's ``PARTITION_KEY`` array of
+    one class label per sample, or where that key is None, the number of
+    samples."""
+    key = family(cfg["family"]).PARTITION_KEY
+    if key is None:
+        return int(next(iter(train.values())).shape[0])
+    return np.asarray(train[key])
+
+
 # --------------------------------------------------------------------------- #
 # the program's seeded registry and partition draws
 # --------------------------------------------------------------------------- #
@@ -125,7 +147,7 @@ def _window_partition(num_samples: int, sizes: np.ndarray,
             for s, n in zip(starts, sizes)]
 
 
-def devices_and_shards(seed: int, cfg: Dict, train_labels: np.ndarray):
+def devices_and_shards(seed: int, cfg: Dict, train_labels):
     """The device registry's shard sizes, the (N, W) zero-padded shard
     table, and the registry itself (distance, interference, CPU frequency
     and sample count per device, float64), drawn as the program draws them from ``default_rng(seed)``:
@@ -133,7 +155,9 @@ def devices_and_shards(seed: int, cfg: Dict, train_labels: np.ndarray):
     shard sizes (integers in [samples_min, samples_max]), then the
     partition: Dirichlet when ``non_iid_alpha`` > 0, disjoint uniform
     shards when every device takes part in every round, else cyclic
-    windows over the pool."""
+    windows over the pool. ``train_labels`` is the pool's class label per
+    sample, or the pool's number of samples where it has none (then
+    ``non_iid_alpha`` has to be 0)."""
     dep, w, l = cfg["deployment"], cfg["wireless"], cfg["ltfl"]
     n = dep["population"]
     rng = np.random.default_rng(int(seed))
@@ -143,13 +167,18 @@ def devices_and_shards(seed: int, cfg: Dict, train_labels: np.ndarray):
                                     w["interference_max"], n),
         "cpu": rng.uniform(w["cpu_min"], w["cpu_max"], n)}
     sizes = rng.integers(l["samples_min"], l["samples_max"] + 1, n)
+    counted = isinstance(train_labels, (int, np.integer))
+    num = int(train_labels) if counted else len(train_labels)
     if dep["non_iid_alpha"] > 0:
+        if counted:
+            raise ValueError("a Dirichlet partition (non_iid_alpha > 0) "
+                             "needs a class label per sample")
         parts = _dirichlet_partition(train_labels, sizes,
                                      dep["non_iid_alpha"], rng)
     elif dep["cohort"] == n:
-        parts = _iid_partition(len(train_labels), sizes, rng)
+        parts = _iid_partition(num, sizes, rng)
     else:
-        parts = _window_partition(len(train_labels), sizes, rng)
+        parts = _window_partition(num, sizes, rng)
     width = int(sizes.max())
     table = np.zeros((n, width), np.int32)
     for u, p in enumerate(parts):
