@@ -143,7 +143,7 @@ def quantizer_phase(params) -> None:
     import jax.numpy as jnp
     import numpy as np
     from repro.core.compressors import ltfl_quantizer
-    from repro.kernels.ops import kernel_quant_compatible
+    from repro.kernels.ops import kernel_quant_block
 
     leaves, treedef = jax.tree_util.tree_flatten(params)
     keys = jax.random.split(jax.random.PRNGKey(3), len(leaves) + 1)
@@ -169,7 +169,7 @@ def quantizer_phase(params) -> None:
                          jax.tree_util.tree_leaves(grads)):
         s, w = flip_share(qk, qr, g, np.asarray(bits))
         share, worst = max(share, s), max(worst, w)
-        if kernel_quant_compatible(g.shape[1:]):
+        if kernel_quant_block(g.shape[1:]) is not None:
             plant_share = min(plant_share,
                               flip_share(g, qr, g, np.asarray(bits))[0])
     log(f"quant: {hlo.count('tpu_custom_call')} kernel sites; compiled vs "

@@ -7,6 +7,7 @@ d_model<=512, <=4 experts) used by the per-arch smoke tests.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, List
 
 from repro.configs.base import (
@@ -17,6 +18,7 @@ from repro.configs.base import (
     SSMConfig,
     ShapeConfig,
     WirelessConfig,
+    YarnScaling,
     SHAPES,
     TRAIN_4K,
     PREFILL_32K,
@@ -105,7 +107,10 @@ def reduce_for_smoke(cfg: ArchConfig) -> ArchConfig:
         sliding_window=min(cfg.sliding_window, 64) if cfg.sliding_window else 0,
     )
     if cfg.moe is not None:
-        kw["moe"] = MoEConfig(
+        # the gate's published keys (norm_topk_prob, scaling, balance loss
+        # weight) carry over; the whole layer at the reduced width
+        kw["moe"] = dataclasses.replace(
+            cfg.moe,
             num_experts=min(cfg.moe.num_experts, 4),
             top_k=min(cfg.moe.top_k, 2),
             d_expert=min(cfg.moe.d_expert, 128),
@@ -113,6 +118,7 @@ def reduce_for_smoke(cfg: ArchConfig) -> ArchConfig:
             d_shared_expert=min(cfg.moe.d_shared_expert, 128),
             first_k_dense=min(cfg.moe.first_k_dense, 1),
             dense_d_ff=min(cfg.moe.dense_d_ff, 256),
+            experts_held=0, first_expert=0,
         )
     if cfg.mla is not None:
         kw["mla"] = MLAConfig(
@@ -161,6 +167,7 @@ __all__ = [
     "MoEConfig",
     "MLAConfig",
     "SSMConfig",
+    "YarnScaling",
     "get_arch",
     "get_shape",
     "list_archs",

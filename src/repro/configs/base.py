@@ -24,11 +24,20 @@ class MoEConfig:
     d_expert: int                    # hidden width of each routed expert
     num_shared_experts: int = 0      # always-on experts (DeepSeek style)
     d_shared_expert: int = 0         # hidden width of the shared expert(s)
-    capacity_factor: float = 1.25    # dispatch capacity per expert
-    router_noise: float = 0.0
-    aux_loss_coef: float = 0.01      # load-balance loss coefficient
+    aux_loss_coef: float = 0.01      # sequence-wise balance loss weight
     first_k_dense: int = 0           # leading layers that use a dense FFN
     dense_d_ff: int = 0              # width of those dense FFNs
+    norm_topk_prob: bool = True      # renormalise the top-k gate weights
+    routed_scaling_factor: float = 1.0   # multiplier of the routed output
+    # the share of the routed experts this layer holds: ``experts_held``
+    # of them from id ``first_expert`` (0 holds all ``num_experts``); the
+    # router still scores all of them
+    experts_held: int = 0
+    first_expert: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -40,6 +49,19 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2 publishes it:
+    ``rope_scaling`` of its config, read by MLA alone."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -77,6 +99,7 @@ class ArchConfig:
     glu: bool = True                 # gated (SwiGLU-style) FFN
     norm: str = "rmsnorm"            # rmsnorm | layernorm
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YarnScaling] = None   # MLA only
     pos_emb: str = "rope"            # rope | learned | none
     tie_embeddings: bool = False
     sliding_window: int = 0          # 0 => full attention

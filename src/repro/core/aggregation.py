@@ -23,6 +23,29 @@ import jax.numpy as jnp
 PyTree = Any
 
 
+def _normalizer(weights: jax.Array, alpha: jax.Array,
+                denom: Optional[jax.Array]):
+    """Eq. 19's weights N_u alpha_u, their total, and the divisor."""
+    w = (weights * alpha).astype(jnp.float32)
+    received = jnp.sum(w)
+    norm = received if denom is None else jnp.asarray(denom, jnp.float32)
+    return w, received, jnp.maximum(norm, 1e-12)
+
+
+def _weighted(w: jax.Array, g: jax.Array) -> jax.Array:
+    return jnp.tensordot(w.astype(g.dtype), g, axes=([0], [0]))
+
+
+def _divided(wg: jax.Array, received: jax.Array, safe: jax.Array):
+    out = wg / safe.astype(wg.dtype)
+    return jnp.where(received > 0, out, jnp.zeros_like(out))
+
+
+def weighted_sum(client_grads: PyTree, w: jax.Array) -> PyTree:
+    """sum_u w_u g_u over the leading client axis of every leaf."""
+    return jax.tree_util.tree_map(lambda g: _weighted(w, g), client_grads)
+
+
 def aggregate(client_grads: PyTree, weights: jax.Array,
               alpha: jax.Array,
               denom: Optional[jax.Array] = None) -> PyTree:
@@ -30,14 +53,16 @@ def aggregate(client_grads: PyTree, weights: jax.Array,
     weights (C,) = N_u (or N_u / pi_u for unbiased partial participation);
     alpha (C,) in {0, 1} (float ok); ``denom`` fixes the normalizer
     instead of sum(weights * alpha)."""
-    w = (weights * alpha).astype(jnp.float32)
-    received = jnp.sum(w)
-    norm = received if denom is None else jnp.asarray(denom, jnp.float32)
-    safe = jnp.maximum(norm, 1e-12)
+    w, received, safe = _normalizer(weights, alpha, denom)
+    return jax.tree_util.tree_map(
+        lambda g: _divided(_weighted(w, g), received, safe), client_grads)
 
-    def leaf(g):
-        wg = jnp.tensordot(w.astype(g.dtype), g, axes=([0], [0]))
-        out = wg / safe.astype(g.dtype)
-        return jnp.where(received > 0, out, jnp.zeros_like(out))
 
-    return jax.tree_util.tree_map(leaf, client_grads)
+def aggregate_sum(summed: PyTree, weights: jax.Array, alpha: jax.Array,
+                  denom: Optional[jax.Array] = None) -> PyTree:
+    """Eq. 19 from the weighted sum sum_u N_u alpha_u Q(g_u) already
+    taken (a cohort streamed in chunks adds it up itself): normalised by
+    sum(weights * alpha), or by ``denom``."""
+    _, received, safe = _normalizer(weights, alpha, denom)
+    return jax.tree_util.tree_map(
+        lambda wg: _divided(wg, received, safe), summed)

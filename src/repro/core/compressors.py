@@ -15,8 +15,9 @@ Provided compressors (the paper's Section-6.1 comparison set):
 * ``ltfl_quantizer``— the paper's stochastic uniform quantizer (Eq. 16-17)
   at a per-client, possibly traced bit-width ``delta`` (0 => passthrough,
   the Fig. 2 no-quant ablation). With ``use_kernels=True``, 2-D-reshapable
-  leaves route through the Pallas kernel (repro.kernels.ops) — the TPU
-  fast path; the jnp path is bit-identical given the same key.
+  leaves route through the Pallas kernel (repro.kernels.ops, block by
+  ``kernel_quant_block``) — the TPU fast path; the jnp path is
+  bit-identical given the same key.
 * ``sign_compressor`` — SignSGD: sign(g) uplink, sign(aggregate) * lr_scale
   majority vote on the server.
 * ``stc_compressor``  — Sattler et al. sparse ternary compression: top-k +
@@ -77,11 +78,12 @@ def ltfl_quantizer(*, use_kernels: bool = False,
         bits = jnp.maximum(delta, 1.0)
         out = []
         for leaf, k in zip(leaves, keys):
-            if use_kernels and kops.kernel_quant_compatible(leaf.shape,
-                                                            kernel_block):
+            block = (kops.kernel_quant_block(leaf.shape, kernel_block)
+                     if use_kernels else None)
+            if block is not None:
                 m2 = leaf.reshape(-1, leaf.shape[-1])
                 q = kops.quantize_dequantize_2d_dyn(
-                    m2, bits, k, block=kernel_block).reshape(leaf.shape)
+                    m2, bits, k, block=block).reshape(leaf.shape)
             else:
                 q = quantize_dequantize(leaf, bits, k)
             out.append(jnp.where(delta > 0, q, leaf))

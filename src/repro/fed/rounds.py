@@ -68,7 +68,7 @@ from repro.core.delay_energy import (
     device_round_delay,
     device_round_energy,
 )
-from repro.core.ltfl_step import make_fl_train_step
+from repro.core.ltfl_step import clients_per_step, make_fl_train_step
 from repro.data import ArrayDataset, ClientBatcher, dirichlet_partition, \
     iid_partition, population_partition
 from repro.fed.population import CohortSampler, Population, UniformSampler
@@ -125,6 +125,13 @@ class RoundRecord:
     # over the round's cohort. 0.0 on the synchronous engines and in the
     # async engine's sync-degenerate configuration.
     staleness: float = 0.0
+    # expert layers' routing counts (scanned engine; NaN without them):
+    # the tokens each held expert took over the cohort, max and mean
+    # over (MoE layer, held expert), and the share of the assignments
+    # that went to experts the layer does not hold
+    expert_tokens_max: float = float("nan")
+    expert_tokens_mean: float = float("nan")
+    absent_share: float = float("nan")
 
 
 class FedRunner:
@@ -240,11 +247,13 @@ class FedRunner:
         # the unified engine: every scheme's round is ONE compiled call,
         # shaped (U,) — cohort sampling swaps values, never shapes
         self._use_kernels = bool(use_kernels)
+        self._clients_per_chunk = clients_per_step(params, self.num_devices)
         step_fn = make_fl_train_step(
             model, self.opt, self.num_devices,
             prune=scheme.uses_prune, prune_kind="magnitude",
             compressor=scheme.compressor(use_kernels=use_kernels),
-            simulate_drops=False, use_kernels=use_kernels)
+            simulate_drops=False, use_kernels=use_kernels,
+            clients_per_chunk=self._clients_per_chunk)
         self.comp_state = step_fn.init_comp_state(params)
         self._step_fn = step_fn          # pure step (the scan engine's body)
         self._step = jax.jit(step_fn)
@@ -345,8 +354,11 @@ class FedRunner:
         """The FIXED seeded eval batches ``evaluate`` scores — split out
         so the scanned engine's in-scan eval head (repro.fed.scan_engine,
         ``control="device"``) can upload the identical batches once and
-        evaluate them inside the compiled segment."""
+        evaluate them inside the compiled segment. A test set smaller
+        than one batch is scored once, as one batch of all its rows."""
         eval_rng = np.random.default_rng(self._eval_rng_seed)
+        if self.test.size < batch:
+            batch, max_batches = self.test.size, 1
         return [self.test.batch(batch, eval_rng)
                 for _ in range(max_batches)]
 

@@ -218,6 +218,27 @@ class RoundLog(NamedTuple):
     # synchronous engine, where the pytree simply has no such leaves
     tau: Optional[jax.Array] = None       # (R, U) staleness tau_i
     admitted: Optional[jax.Array] = None  # (R, U) buffer admission mask
+    # expert layers' routing (a model with ``loss_and_counts``); None
+    # otherwise: the tokens each held expert took over the cohort, max
+    # and mean over the (MoE layer, held expert) pairs, and the share of
+    # the token-expert assignments that went to experts not held
+    expert_tokens_max: Optional[jax.Array] = None   # (R,)
+    expert_tokens_mean: Optional[jax.Array] = None  # (R,)
+    absent_share: Optional[jax.Array] = None        # (R,)
+
+
+class _OverwritesWeights:
+    """A jitted segment ``fn(weights, rest_of_carry, ...)`` that donates
+    the weights, called and lowered as ``_segment(carry, ...)``."""
+
+    def __init__(self, fn):
+        self.fn = fn
+
+    def __call__(self, carry, *args):
+        return self.fn(carry[0], carry[1:], *args)
+
+    def lower(self, carry, *args):
+        return self.fn.lower(carry[0], carry[1:], *args)
 
 
 def make_scanned_step(step_fn: Callable) -> Callable:
@@ -453,6 +474,18 @@ class ScanRunner(FedRunner):
         self._reads = spans.Reads()
         self._seg = 0
         self._seg_jit = jax.jit(self._segment, static_argnums=(4, 5))
+        # a cohort streamed because its trees do not fit keeps its weights
+        # on the host between segments, and each segment overwrites the
+        # device copy it is handed (the caller's first weights included):
+        # the device then holds one tree of weights, not that and whatever
+        # copies the caller keeps
+        self._host_weights = self._clients_per_chunk < self.num_devices
+        if self._host_weights:
+            def _segment(weights, rest, *args):    # names the XLA module
+                return self._segment((weights,) + rest, *args)
+
+            self._seg_jit = _OverwritesWeights(jax.jit(
+                _segment, static_argnums=(5, 6), donate_argnums=0))
         self._sweep_jit = jax.jit(
             jax.vmap(self._segment, in_axes=(0, 0, 0, None, None, None)),
             static_argnums=(4, 5))
@@ -816,6 +849,15 @@ class ScanRunner(FedRunner):
                                        params)
             else:
                 acc = jnp.float32(jnp.nan)
+            routed = {}
+            if "counts" in m:
+                held, absent = (m["counts"]["expert_tokens"],
+                                jnp.sum(m["counts"]["absent"]))
+                routed = dict(
+                    expert_tokens_max=jnp.max(held),
+                    expert_tokens_mean=jnp.mean(held),
+                    absent_share=absent / jnp.maximum(
+                        jnp.sum(held) + absent, 1.0))
             log = RoundLog(train_loss=m["loss"], delay=delay, energy=energy,
                            received=jnp.sum(alpha),
                            range_sq=m["range_sq"], gap_delta=gap_delta,
@@ -825,7 +867,7 @@ class ScanRunner(FedRunner):
                            test_acc=acc, rho_mean=jnp.mean(rho),
                            delta_mean=jnp.mean(delta),
                            power_mean=jnp.mean(power),
-                           tau=tau, admitted=admitted)
+                           tau=tau, admitted=admitted, **routed)
             return params, opt_state, comp_state, range_sq, log
 
         if xs is not None:               # host rng: stacked replay inputs
@@ -1085,6 +1127,8 @@ class ScanRunner(FedRunner):
             with spans.span(spans.FETCH, seg=seg):
                 host = self._fetch_segment(carry, log)
             self.params, self.opt_state, self.comp_state = carry[:3]
+            if self._host_weights:
+                self.params = self._reads.tree(self.params)
             cohorts = host["cohort"]
             if self.rng != "device":
                 touched = np.unique(cohorts)
@@ -1173,7 +1217,9 @@ class ScanRunner(FedRunner):
         for field in ("train_loss", "delay", "energy", "received",
                       "range_sq", "gap_delta", "rho_u", "pers", "ns_u",
                       "inclusion", "agg_denom", "tau", "test_acc",
-                      "rho_mean", "delta_mean", "power_mean"):
+                      "rho_mean", "delta_mean", "power_mean",
+                      "expert_tokens_max", "expert_tokens_mean",
+                      "absent_share"):
             value = getattr(log, field)
             host[field] = None if value is None else read(value, np.float64)
         return host
@@ -1221,6 +1267,9 @@ class ScanRunner(FedRunner):
                 participation=self.cohort_size / self.population_size,
                 staleness=(float(np.mean(taus[i]))
                            if taus is not None else 0.0),
+                **{k: float(host[k][i]) for k in (
+                    "expert_tokens_max", "expert_tokens_mean",
+                    "absent_share") if host[k] is not None},
             )
             self.history.append(rec)
             if not in_scan_feedback:

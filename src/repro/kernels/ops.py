@@ -7,7 +7,7 @@ kernels compile on a TPU and run in the Pallas interpreter on the CPU
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,20 +25,31 @@ def quantize_dequantize_2d(g: jax.Array, bits: int, key: jax.Array,
     return quantize_dequantize_2d_dyn(g, jnp.float32(bits), key, block=block)
 
 
-def kernel_quant_compatible(shape: Tuple[int, ...],
-                            block=(256, 256)) -> bool:
-    """True when a >=2-D tensor, viewed as (prod(leading), last), tiles
-    evenly for the quantization kernels. Leaves failing this stay on the
-    jnp path (the two are bit-identical given the same key)."""
+# the narrowest lane block the quantizer kernel takes: a TPU vreg's lanes
+LANES = 128
+
+
+def kernel_quant_block(shape: Tuple[int, ...], block=(256, 256)
+                       ) -> Optional[Tuple[int, int]]:
+    """The quantizer kernel's block for a >=2-D tensor viewed as
+    (prod(leading), last): ``block`` where it tiles evenly (each side cut
+    to the tensor's); else, where only the last side fails and is a
+    multiple of 128 lanes (an expert's 1408 = 11 x 128), ``block`` with
+    128 lanes; else None: the leaf stays on the jnp path (the two are
+    bit-identical given the same key)."""
     if len(shape) < 2:
-        return False
+        return None
     m = 1
     for d in shape[:-1]:
         m *= d
     n = shape[-1]
-    if m == 0 or n == 0:
-        return False
-    return m % min(block[0], m) == 0 and n % min(block[1], n) == 0
+    if m == 0 or n == 0 or m % min(block[0], m):
+        return None
+    if n % min(block[1], n) == 0:
+        return tuple(block)
+    if n % LANES == 0:
+        return block[0], LANES
+    return None
 
 
 def quantize_dequantize_2d_dyn(g: jax.Array, bits: jax.Array, key: jax.Array,

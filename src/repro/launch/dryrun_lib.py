@@ -16,7 +16,7 @@ ICI per link, 16 GB HBM per chip.
 
 ``variant`` is the hillclimb hook (EXPERIMENTS.md section Perf): a dict of
 overrides such as {"prune": False}, {"agg": "int8"}, {"fsdp": True},
-{"remat": "dots"}, {"moe_group": 1024}.
+{"remat": "dots"}, {"moe_token": "dense"}.
 """
 from __future__ import annotations
 
@@ -97,11 +97,7 @@ class DryRunRecord:
 # --------------------------------------------------------------------------- #
 def _arch_for(arch_name: str, shape: ShapeConfig,
               variant: Dict[str, Any]) -> ArchConfig:
-    arch = configs.arch_for_shape(configs.get_arch(arch_name), shape)
-    if variant.get("moe_group") and arch.moe is not None:
-        # group size is a module constant; patched at build time below
-        pass
-    return arch
+    return configs.arch_for_shape(configs.get_arch(arch_name), shape)
 
 
 
@@ -343,10 +339,7 @@ def run_pair(arch_name: str, shape_name: str, *, multi_pod: bool = False,
     from repro.models import moe as moe_mod
     from repro.models import rwkv6 as rwkv_mod
     from repro.models import mamba2 as mamba_mod
-    saved_moe = (moe_mod.GROUP_SIZE, moe_mod.TOKEN_DISPATCH,
-                 rwkv_mod.CHUNK, mamba_mod.CHUNK)
-    if variant.get("moe_group") and arch.moe is not None:
-        moe_mod.GROUP_SIZE = int(variant["moe_group"])
+    saved_moe = (moe_mod.TOKEN_DISPATCH, rwkv_mod.CHUNK, mamba_mod.CHUNK)
     if variant.get("moe_token") and arch.moe is not None:
         moe_mod.TOKEN_DISPATCH = variant["moe_token"]
     if variant.get("rwkv_chunk"):
@@ -364,8 +357,8 @@ def run_pair(arch_name: str, shape_name: str, *, multi_pod: bool = False,
                 lowered = jf.lower(*args)
                 compiled = lowered.compile()
     finally:
-        (moe_mod.GROUP_SIZE, moe_mod.TOKEN_DISPATCH,
-         rwkv_mod.CHUNK, mamba_mod.CHUNK) = saved_moe
+        (moe_mod.TOKEN_DISPATCH, rwkv_mod.CHUNK,
+         mamba_mod.CHUNK) = saved_moe
     dt = time.time() - t0
     rec = analyze(arch, shape, mesh, lowered, compiled, n_clients, variant,
                   dt)

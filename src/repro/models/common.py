@@ -140,14 +140,67 @@ def activation(name: str) -> Callable[[jax.Array], jax.Array]:
     raise ValueError(f"unknown activation {name!r}")
 
 
-def rope_tables(seq_len: int, head_dim: int, theta: float,
-                offset: int = 0) -> Tuple[jax.Array, jax.Array]:
-    """Rotary embedding cos/sin tables of shape (seq_len, head_dim/2), f32."""
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_correction_dim(rotations: float, dim: int, theta: float,
+                         max_pos: int) -> float:
+    return (dim * math.log(max_pos / (rotations * 2 * math.pi))) / (
+        2 * math.log(theta))
+
+
+def rope_inv_freq(head_dim: int, theta: float, scaling=None) -> jax.Array:
+    """The (head_dim/2,) inverse frequencies, f32. Under YaRN scaling
+    (``configs.base.YarnScaling``, DeepSeek-V2's ``yarn_*`` functions):
+    the extrapolated frequencies theta^(-2i/d) and the interpolated ones
+    (over ``factor``) blended by a linear ramp between the correction
+    dimensions of ``beta_fast`` and ``beta_slow`` rotations over
+    ``original_max_position_embeddings``."""
     half = head_dim // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    extra = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if scaling is None:
+        return extra
+    orig = scaling.original_max_position_embeddings
+    low = max(math.floor(_yarn_correction_dim(
+        scaling.beta_fast, head_dim, theta, orig)), 0)
+    high = min(math.ceil(_yarn_correction_dim(
+        scaling.beta_slow, head_dim, theta, orig)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp               # 1: extrapolate, 0: interpolate
+    return extra / scaling.factor * (1.0 - keep) + extra * keep
+
+
+def rope_mscale(scaling=None) -> float:
+    """YaRN's multiplier of the cos/sin tables: mscale over
+    mscale_all_dim, each as 0.1 m ln(factor) + 1 (1 without scaling)."""
+    if scaling is None:
+        return 1.0
+    return (_yarn_mscale(scaling.factor, scaling.mscale)
+            / _yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+
+
+def softmax_mscale(scaling=None) -> float:
+    """YaRN's factor on the attention softmax scale: the square of
+    0.1 mscale_all_dim ln(factor) + 1 (1 without scaling)."""
+    if scaling is None or not scaling.mscale_all_dim:
+        return 1.0
+    return _yarn_mscale(scaling.factor, scaling.mscale_all_dim) ** 2
+
+
+def rope_tables(seq_len: int, head_dim: int, theta: float,
+                offset: int = 0, scaling=None
+                ) -> Tuple[jax.Array, jax.Array]:
+    """Rotary embedding cos/sin tables of shape (seq_len, head_dim/2), f32;
+    ``scaling`` is YaRN's (``rope_inv_freq``, ``rope_mscale``)."""
+    freqs = rope_inv_freq(head_dim, theta, scaling)
     pos = jnp.arange(offset, offset + seq_len, dtype=jnp.float32)
     ang = pos[:, None] * freqs[None, :]
-    return jnp.cos(ang), jnp.sin(ang)
+    cos, sin, m = jnp.cos(ang), jnp.sin(ang), rope_mscale(scaling)
+    return (cos, sin) if m == 1.0 else (cos * m, sin * m)
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -160,13 +213,15 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 
 
 def apply_rope_at(x: jax.Array, pos: jax.Array, head_dim: int,
-                  theta: float) -> jax.Array:
+                  theta: float, scaling=None) -> jax.Array:
     """Rope for decode: x (batch, heads, head_dim), pos (batch,) int32."""
     half = head_dim // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = rope_inv_freq(head_dim, theta, scaling)
     ang = pos.astype(jnp.float32)[:, None] * freqs[None, :]   # (B, half)
-    c = jnp.cos(ang)[:, None, :].astype(x.dtype)
-    s = jnp.sin(ang)[:, None, :].astype(x.dtype)
+    c, s, m = jnp.cos(ang), jnp.sin(ang), rope_mscale(scaling)
+    if m != 1.0:
+        c, s = c * m, s * m
+    c, s = c[:, None, :].astype(x.dtype), s[:, None, :].astype(x.dtype)
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
 

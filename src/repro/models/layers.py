@@ -99,13 +99,13 @@ def _repeat_kv(k: jax.Array, groups: int) -> jax.Array:
     return jnp.repeat(k, groups, axis=2)
 
 
-def _attend_full(q, k, v, mask_bias):
+def _attend_full(q, k, v, mask_bias, scale=None):
     """Grouped-query attention without materializing repeated KV.
 
-    q (B,Sq,KV,G,hd); k/v (B,Skv,KV,hd); mask_bias (Sq,Skv) or None.
-    Returns (B,Sq,KV,G,hd).
+    q (B,Sq,KV,G,hd); k/v (B,Skv,KV,hd); mask_bias (Sq,Skv) or None;
+    ``scale`` of the scores, hd^-0.5 by default. Returns (B,Sq,KV,G,hd).
     """
-    scale = q.shape[-1] ** -0.5
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
     scores = jnp.einsum("bqkgd,bskd->bkgqs", q, k).astype(jnp.float32) \
         * scale
     if mask_bias is not None:
@@ -125,8 +125,12 @@ def _causal_bias(sq: int, skv: int, q_offset: int,
 
 
 def attend(cfg: ArchConfig, q, k, v, *, causal: bool,
-           q_offset: int = 0) -> jax.Array:
-    """Dispatch between full and query-chunked attention.
+           q_offset: int = 0, scale: Optional[float] = None,
+           chunk_above: int = CHUNKED_ATTN_THRESHOLD) -> jax.Array:
+    """Dispatch between full and query-chunked attention: queries run in
+    chunks of ``Q_CHUNK`` once there are more than ``chunk_above`` of
+    them, each chunk recomputed in the backward pass rather than its
+    scores kept.
 
     q (B,Sq,H,hd); k,v (B,Skv,KV,hd). Returns (B,Sq,H,hd).
     """
@@ -137,9 +141,9 @@ def attend(cfg: ArchConfig, q, k, v, *, causal: bool,
     qg = q.reshape(B, sq, kv, groups, hd)
     skv = k.shape[1]
     window = cfg.sliding_window
-    if sq <= CHUNKED_ATTN_THRESHOLD:
+    if sq <= chunk_above:
         bias = _causal_bias(sq, skv, q_offset, window) if causal else None
-        out = _attend_full(qg, k, v, bias)
+        out = _attend_full(qg, k, v, bias, scale)
         return out.reshape(B, sq, H, hd_v)
 
     # -- query-chunked path: never materialize (Sq, Skv) at once ---------- #
@@ -160,9 +164,9 @@ def attend(cfg: ArchConfig, q, k, v, *, causal: bool,
             bias = jnp.where(m, 0.0, NEG_INF).astype(jnp.float32)
         else:
             bias = None
-        return _attend_full(qc, k, v, bias)
+        return _attend_full(qc, k, v, bias, scale)
 
-    out = jax.lax.map(lambda args: one_chunk(*args),
+    out = jax.lax.map(jax.checkpoint(lambda args: one_chunk(*args)),
                       (jnp.arange(n_chunks), qs))
     out = jnp.moveaxis(out, 0, 1)  # (B, n_chunks, qc, KV, G, hd_v)
     return out.reshape(B, sq, H, hd_v)

@@ -116,45 +116,67 @@ class DecoderLM:
         x = x + a
         h2 = apply_norm(cfg, x, layer_p["ln2"])
         if moe_layer:
-            f, aux = moe_mod.moe_apply(cfg, layer_p["ffn"], h2)
+            f, aux, counts = moe_mod.moe_apply(cfg, layer_p["ffn"], h2)
         else:
             f, aux = mlp_apply(cfg, layer_p["ffn"], h2), jnp.zeros((),
                                                                    jnp.float32)
+            counts = {}
         x = x + f
         x = shard_hint(x, ("batch", "act_seq", "act_embed"))
-        return x, aux
+        return x, (aux, counts)
 
-    def forward(self, params, batch) -> Tuple[jax.Array, jax.Array]:
-        """-> (logits (B, S_total, V), aux_loss scalar)."""
+    def _forward(self, params, batch):
+        """-> (logits (B, S_total, V), aux_loss scalar, the MoE layers'
+        routing counts stacked over them: ``{}`` without MoE layers)."""
         cfg = self.cfg
         x = self._embed_inputs(params, batch)
         aux_total = jnp.zeros((), jnp.float32)
-        for lp in params.get("prefix_layers", []):
-            x, aux = self._train_block(lp, x, moe_layer=False)
-            aux_total += aux
         moe_layer = cfg.moe is not None
 
-        def body(carry, layer_p):
-            return self._train_block(layer_p, carry, moe_layer)
-
+        block = self._train_block
         if self.remat:
-            body = jax.checkpoint(
-                body, policy=jax.checkpoint_policies.nothing_saveable)
-        x, auxes = jax.lax.scan(body, x, params["layers"])
+            block = jax.checkpoint(
+                block, policy=jax.checkpoint_policies.nothing_saveable,
+                static_argnums=(2,))
+        for lp in params.get("prefix_layers", []):
+            x, (aux, _) = block(lp, x, False)
+            aux_total += aux
+        x, (auxes, counts) = jax.lax.scan(
+            lambda carry, layer_p: block(layer_p, carry, moe_layer), x,
+            params["layers"])
         aux_total = aux_total + jnp.sum(auxes)
         x = apply_norm(cfg, x, params["final_norm"])
         logits = lm_head(cfg, params["embed"], x)
-        return logits, aux_total
+        return logits, aux_total, counts
+
+    def forward(self, params, batch) -> Tuple[jax.Array, jax.Array]:
+        """-> (logits (B, S_total, V), aux_loss scalar)."""
+        logits, aux, _ = self._forward(params, batch)
+        return logits, aux
+
+    def _text_logits(self, logits):
+        if self.cfg.family == "vlm":
+            return logits[:, self.cfg.num_image_tokens:, :]
+        return logits
+
+    def loss_and_counts(self, params, batch):
+        """Next-token loss plus the balance loss, and the MoE layers'
+        routing counts (``moe.moe_apply``) stacked over them:
+        ``expert_tokens`` (layers, held) and ``absent`` (layers,)."""
+        logits, aux, counts = self._forward(params, batch)
+        logits = self._text_logits(logits)
+        # next-token prediction: position t predicts token t + 1
+        loss = cross_entropy_loss(logits[:, :-1, :], batch["labels"][:, 1:])
+        return loss + aux, counts
 
     def loss(self, params, batch) -> jax.Array:
-        cfg = self.cfg
-        logits, aux = self.forward(params, batch)
-        if cfg.family == "vlm":
-            n_img = cfg.num_image_tokens
-            logits = logits[:, n_img:, :]
-        # next-token prediction
-        loss = cross_entropy_loss(logits[:, :-1, :], batch["labels"][:, 1:])
-        return loss + aux
+        return self.loss_and_counts(params, batch)[0]
+
+    def accuracy(self, params, batch) -> jax.Array:
+        """Next-token accuracy, with the shift of ``loss``."""
+        logits = self._text_logits(self._forward(params, batch)[0])
+        pred = jnp.argmax(logits[:, :-1, :], axis=-1)
+        return jnp.mean((pred == batch["labels"][:, 1:]).astype(jnp.float32))
 
     # ------------------------------------------------------------------ #
     # decode

@@ -31,6 +31,7 @@ import contextlib
 import re
 from typing import Dict, Iterator, Optional
 
+import jax
 import numpy as np
 from jax.profiler import TraceAnnotation
 
@@ -47,6 +48,11 @@ CONTROL = "repro.control"      # Algorithm 1 in-scan, feedback, admission
 EVAL = "repro.eval"            # the in-scan eval head
 STAGES = (PRUNE, GRAD, RANGE, COMPRESS, AGGREGATE, UPDATE, CHANNEL,
           SAMPLER, CONTROL, EVAL)
+# stages of a model's own layers, inside GRAD (and EVAL): an expert
+# layer's (models/moe.py)
+ROUTE = "repro.route"          # scores, top-k, the grouped rows, combine
+EXPERTS = "repro.experts"      # the held experts' grouped products
+MODEL_STAGES = (ROUTE, EXPERTS)
 
 # the engine's host work (span)
 RUN = "repro.run"              # ScanRunner.run
@@ -73,6 +79,14 @@ class Reads:
         self.count += 1
         self.bytes += int(x.nbytes)
         return np.asarray(x, dtype)
+
+    def tree(self, tree):
+        """Every leaf of ``tree`` read at once (``jax.device_get``, whose
+        copies run together), each counted."""
+        for x in jax.tree_util.tree_leaves(tree):
+            self.count += 1
+            self.bytes += int(x.nbytes)
+        return jax.device_get(tree)
 
 
 @contextlib.contextmanager
@@ -107,7 +121,7 @@ def op_paths(hlo_text: str) -> Dict[str, str]:
 def stage_of(path: str) -> Optional[str]:
     """The innermost stage scope in an ``op_name`` path, or None."""
     best, at = None, -1
-    for stage in STAGES:
+    for stage in STAGES + MODEL_STAGES:
         i = path.rfind(stage)
         if i > at:
             best, at = stage, i

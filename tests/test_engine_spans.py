@@ -226,3 +226,40 @@ def test_op_paths_and_innermost_stage():
     assert spans.stage_of("jit(_segment)/while/body/add") is None
     assert spans.stage_of("a/repro.sampler/b/repro.channel/c") == \
         spans.CHANNEL
+
+
+def test_expert_stages_and_routing_counters():
+    """An expert layer's share through the round: its routing and grouped
+    products carry ``repro.route`` and ``repro.experts`` in the compiled
+    segment's op names, inside ``repro.grad`` (and ``repro.eval``; the
+    routing's index arithmetic, which reads no input, is computed once
+    outside them); each round's record holds
+    the tokens its held experts took over the cohort (max and mean over
+    MoE layer and expert) and the share of assignments to the experts it
+    does not hold."""
+    from repro import configs
+    from repro.configs.deepseek_v2_lite_16b import CONFIG, ep_share
+    from repro.models import build_model
+    small = configs.reduce_for_smoke(CONFIG)
+    cfg = ep_share(small, chips=2, moe_layers=1)          # 2 of 4 experts
+    model = build_model(cfg)
+    params = jax.tree_util.tree_map(lambda x: x.astype(np.float32),
+                                    model.init(jax.random.PRNGKey(0)))
+    rows = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (300, 16)).astype(np.int32)
+    data = ArrayDataset({"tokens": rows, "labels": rows})
+    runner = ScanRunner(model, params, LTFL, data, ArrayDataset(
+        {"tokens": rows[:4], "labels": rows[:4]}), FedSGDScheme(),
+        batch_size=2, seed=0, eval_every=2, rng="device", control="device")
+    paths = spans.op_paths(runner.lower_segment(2).compile().as_text())
+    for stage in (spans.ROUTE, spans.EXPERTS):
+        mine = [p for p in paths.values() if spans.stage_of(p) == stage]
+        assert any(spans.GRAD in p for p in mine), stage
+        assert any(spans.EVAL in p for p in mine), stage
+    records = runner.run(2)
+    k, u, held = cfg.moe.top_k, LTFL.num_devices, cfg.moe.held
+    for r in records:
+        assigned = u * 2 * 16 * k                    # one MoE layer
+        assert 0 < r.expert_tokens_mean <= r.expert_tokens_max <= u * 2 * 16
+        assert math.isclose(r.absent_share, 1 - held * r.expert_tokens_mean
+                            / assigned, rel_tol=1e-6)

@@ -124,3 +124,103 @@ def test_plain_step(setup):
     step = jax.jit(make_plain_train_step(model, opt))
     p, s, m = step(params, opt.init(params), batch, jax.random.PRNGKey(0))
     assert np.isfinite(float(m["loss"]))
+
+
+# --------------------------------------------------------------------------- #
+# clients streamed k at a time (clients_per_chunk)
+# --------------------------------------------------------------------------- #
+C5 = 5
+STREAM = {"ltfl": dict(compressor="ltfl", prune_kind="magnitude"),
+          "fedsgd": dict(compressor="none", prune=False),
+          "stc": dict(compressor="stc", prune=False)}
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    from repro.models import MLP, MLPConfig
+    model = MLP(MLPConfig(input_shape=(4, 4, 3), hidden=(16,)))
+    params = model.init(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (C5, 6, 4, 4, 3))
+    y = jax.random.randint(jax.random.PRNGKey(2), (C5, 6), 0, 10)
+    return model, params, {"images": x, "labels": y}
+
+
+def _streamed_step(model, scheme, k):
+    step_fn = make_fl_train_step(model, sgd(1.0), C5, clients_per_chunk=k,
+                                 simulate_drops=False, **STREAM[scheme])
+    return step_fn, jax.jit(step_fn)
+
+
+def _stream_controls(weights):
+    return {"rho": jnp.array([0.0, 0.1, 0.3, 0.5, 0.2]),
+            "delta": jnp.array([8.0, 3.0, 2.0, 6.0, 1.0]),
+            "alpha": jnp.array([1.0, 1.0, 0.0, 1.0, 1.0]),
+            "weights": weights}
+
+
+@pytest.mark.parametrize("scheme", sorted(STREAM))
+def test_streamed_clients_match_the_whole_cohort(tiny, scheme):
+    """k in {1, 2, 3} of 5 clients against k = 5: each client's loss and
+    range statistic, the STC residuals and each client's upload (its
+    weight alone non-zero: the step then moves the weights by that
+    upload) bit for bit; the aggregate and the mean loss to float32
+    summation order (a relative 1e-6: five float32 terms summed in
+    another order)."""
+    model, params, batch = tiny
+    opt_state = sgd(1.0).init(params)
+    weights = jnp.array([40.0, 55.0, 61.0, 47.0, 52.0])
+    key = jax.random.PRNGKey(7)
+    runs = {}
+    for k in (C5, 1, 2, 3):
+        step_fn, step = _streamed_step(model, scheme, k)
+        cs = step_fn.init_comp_state(params)
+        out = step(params, opt_state, cs, batch, _stream_controls(weights),
+                   key)
+        alone = [step(params, opt_state, cs, batch, _stream_controls(
+            jnp.zeros(C5).at[u].set(1.0)), key)[0] for u in (0, 3, 4)]
+        runs[k] = (out, alone)
+    (p_all, _, cs_all, m_all), alone_all = runs[C5]
+    for k in (1, 2, 3):
+        (p, _, cs, m), alone = runs[k]
+        np.testing.assert_array_equal(m["range_sq"], m_all["range_sq"])
+        np.testing.assert_array_equal(m["losses"], m_all["losses"])
+        np.testing.assert_allclose(m["loss"], m_all["loss"], rtol=1e-6)
+        for a, b in zip(jax.tree_util.tree_leaves((cs, alone)),
+                        jax.tree_util.tree_leaves((cs_all, alone_all))):
+            np.testing.assert_array_equal(a, b)
+        for a, b in zip(jax.tree_util.tree_leaves(p),
+                        jax.tree_util.tree_leaves(p_all)):
+            np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
+
+
+def test_whole_cohort_step_has_no_loop_over_clients(tiny):
+    """At k = C the step is the vmap over all clients: its jaxpr holds no
+    scan; streamed, it holds one."""
+    model, params, batch = tiny
+    ctl = _stream_controls(jnp.ones(C5))
+    for k, loops in ((C5, False), (2, True)):
+        step_fn, _ = _streamed_step(model, "fedsgd", k)
+        jaxpr = jax.make_jaxpr(step_fn)(
+            params, (), (), batch, ctl, jax.random.PRNGKey(0))
+        assert ("scan" in str(jaxpr)) == loops, k
+
+
+def test_clients_per_step_fills_the_free_memory(monkeypatch):
+    """All clients without a device memory limit; otherwise the most
+    whose work (five trees each) fits beside two trees, at least one."""
+    from repro.core import ltfl_step
+
+    class Dev:
+        def __init__(self, stats):
+            self.stats = stats
+
+        def memory_stats(self):
+            return self.stats
+
+    tree = {"w": jnp.zeros((1000,), jnp.float32)}          # 4,000 bytes
+    for stats, k in ((None, 8), ({"bytes_limit": 10 ** 9}, 8),
+                     ({"bytes_limit": 8000 + 3 * 20000, "bytes_in_use": 0},
+                      3),
+                     ({"bytes_limit": 50000, "bytes_in_use": 45000}, 1)):
+        monkeypatch.setattr(jax, "devices", lambda s=stats: [Dev(s)])
+        assert ltfl_step.clients_per_step(tree, 8) == k, stats

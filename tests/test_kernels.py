@@ -109,3 +109,20 @@ def test_interpret_mode_refuses_other_backends(monkeypatch):
     monkeypatch.setattr(backend.jax, "default_backend", lambda: "gpu")
     with pytest.raises(RuntimeError, match="gpu"):
         backend.interpret_mode()
+
+
+def test_quantizer_kernel_takes_128_lane_leaves():
+    """An expert's (2, 256, 1408) leaf (1408 = 11 x 128 lanes, not a
+    multiple of 256) goes through the quantizer kernel with a 128-lane
+    block, and its upload equals the jnp path's for the same key; leaves
+    that tile at 256 keep their block, and a last side that is not a
+    multiple of 128 stays on the jnp path."""
+    from repro.core.compressors import ltfl_quantizer
+    assert ops.kernel_quant_block((2, 256, 1408)) == (256, 128)
+    assert ops.kernel_quant_block((3, 3, 256, 512)) == (256, 256)
+    assert ops.kernel_quant_block((2048, 576)) is None
+    g = {"w": jax.random.normal(jax.random.PRNGKey(3), (2, 256, 1408))}
+    key, delta = jax.random.PRNGKey(4), jnp.float32(4.0)
+    out_k, _ = ltfl_quantizer(use_kernels=True).compress(g, delta, key, ())
+    out_j, _ = ltfl_quantizer(use_kernels=False).compress(g, delta, key, ())
+    np.testing.assert_array_equal(out_k["w"], out_j["w"])

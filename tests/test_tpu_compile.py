@@ -111,3 +111,33 @@ def test_block_sparse_matmul_compiles(one_chip, mn, dtype):
         _spec(one_chip, (512, k), dtype), _spec(one_chip, mn, dtype),
         _spec(one_chip, (k // TILE, n // TILE), jnp.bool_))
     assert "tpu_custom_call" in txt
+
+
+def test_quantizer_compiles_with_128_lanes(one_chip):
+    """DeepSeek-V2-Lite's stacked expert leaf (4 layers x 8 experts x 2048,
+    1408 = 11 x 128) viewed as 2-D, in 128-lane blocks."""
+    mn = (4 * 8 * 2048, 1408)
+    txt = _compiled_text(
+        lambda g, r, s: stochastic_quant_dyn(g, r, s[0], s[1], s[2],
+                                             block=(256, 128),
+                                             interpret=False),
+        _spec(one_chip, mn), _spec(one_chip, mn), _spec(one_chip, (3,)))
+    assert "tpu_custom_call" in txt
+
+
+def test_expert_products_compile_with_their_gradient(one_chip):
+    """The grouped products of one chip's 8 experts (2048 -> 1408) over
+    a buffer of 8,192 tokens' top-6 assignments, forward and backward,
+    run one client after another under the client vmap: kernels the
+    compiler names ``ragged-dot``."""
+    from repro.models.moe import expert_products
+
+    def loss(x, w, sizes):
+        return jnp.sum(jnp.tanh(expert_products(x, w, sizes)))
+
+    txt = _compiled_text(
+        jax.vmap(jax.grad(loss, argnums=(0, 1))),
+        _spec(one_chip, (2, 8192 * 6, 2048)),
+        _spec(one_chip, (2, 8, 2048, 1408)),
+        _spec(one_chip, (2, 8), jnp.int32))
+    assert "ragged-dot" in txt and "tpu_custom_call" in txt
